@@ -45,6 +45,20 @@ def dataset(name: str, weighted: bool = False):
     return path, v, e
 
 
+def require_cpu_backend(what: str) -> None:
+    """Refuse to start ``what``, a child process on forced CPU devices,
+    unless this process runs on the CPU backend too.  On an accelerator
+    host the child's numbers would be CPU timings under the sharded
+    rows' names, and a parent that has used JAX already holds the chip."""
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"{what} times forced CPU devices in a child process; this "
+            f"process runs on {backend!r}, so its rows would not measure "
+            f"the {backend} mesh.  Run it with JAX_PLATFORMS=cpu.")
+
+
 def timeit(fn, *, repeat: int = 3, warmup: int = 1) -> float:
     """Median seconds over `repeat` runs (paper averages 5; we use
     median-of-3 to bound suite runtime)."""

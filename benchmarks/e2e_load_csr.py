@@ -80,7 +80,7 @@ import sys
 
 import numpy as np
 
-from .common import dataset, emit, timeit
+from .common import dataset, emit, require_cpu_backend, timeit
 
 
 def _compact_edges(src_b, dst_b, w_b, counts, total_cap):
@@ -237,6 +237,8 @@ def _sharded_times(path, v, repeat):
     ``--xla_force_host_platform_device_count=4`` so the threadpool split
     is identical across the four timings."""
     import subprocess
+
+    require_cpu_backend("the sharded e2e rows")
 
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")

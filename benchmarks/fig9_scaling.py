@@ -21,7 +21,7 @@ import sys
 
 import numpy as np
 
-from .common import dataset, emit, timeit
+from .common import dataset, emit, require_cpu_backend, timeit
 
 _SHARD_SWEEP_CODE = """
 import json, sys, time
@@ -44,6 +44,7 @@ print("SWEEP_JSON " + json.dumps(out))
 
 
 def _shard_sweep(path, v):
+    require_cpu_backend("the fig9 shard sweep")
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=4")
     env["PYTHONPATH"] = os.pathsep.join(

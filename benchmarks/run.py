@@ -28,6 +28,8 @@ SUITES = {
 
 
 def main() -> None:
+    from repro.core import env
+    env.use_compilation_cache()
     want = sys.argv[1:] or list(SUITES)
     print("name,us_per_call,derived")
     for name in want:

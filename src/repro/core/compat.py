@@ -1,56 +1,31 @@
-"""JAX API compatibility shims.
+"""Mesh and shard_map helpers over the installed JAX API.
 
-The repo targets the modern mesh/shard_map surface (``jax.shard_map``
-with ``check_vma``/``axis_names``, ``jax.make_mesh`` with explicit
-``AxisType``), but the pinned container ships jax 0.4.37 where those
-spell ``jax.experimental.shard_map.shard_map`` with ``check_rep``/
-``auto`` and ``make_mesh`` takes no ``axis_types``.  Every mesh or
-shard_map construction in src/ and tests/ goes through this module so
-the code runs unchanged on either API.
+Every mesh or shard_map construction in the repo goes through these
+three wrappers, so they fix one convention in one place: meshes use
+``AxisType.Auto`` axes, and shard_map runs unchecked
+(``check_vma=False``).
 """
 from __future__ import annotations
 
 import jax
 import numpy as np
-
-try:                                    # newer jax
-    from jax.sharding import AxisType
-except ImportError:                     # 0.4.x
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def make_mesh(shape, axes):
-    """jax.make_mesh with Auto axis types when the API supports them."""
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with Auto axis types."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def device_mesh(devices, axes):
-    """jax.sharding.Mesh over an explicit device array."""
-    devices = np.asarray(devices)
-    if AxisType is not None:
-        return jax.sharding.Mesh(devices, axes,
-                                 axis_types=(AxisType.Auto,) * len(axes))
-    return jax.sharding.Mesh(devices, axes)
+    """``jax.sharding.Mesh`` over an explicit device array, Auto axes."""
+    return jax.sharding.Mesh(np.asarray(devices), axes,
+                             axis_types=(AxisType.Auto,) * len(axes))
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None):
-    """Un-checked shard_map on either API.
-
-    ``axis_names`` (when given) is the set of *manual* axes, matching
-    the modern keyword; on old jax it becomes the complement ``auto``
-    set of the experimental entry point.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False, **kw)
-    # Old jax: partial-auto shard_map lowers axis_index to a PartitionId
-    # instruction the CPU SPMD partitioner rejects, so run fully manual.
-    # Bodies in this repo only issue collectives over their manual axes
-    # and take replicated (P()) specs elsewhere, so results are identical;
-    # only auto-axis GSPMD propagation is lost, which no caller relies on.
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
+    """Unchecked ``jax.shard_map``; ``axis_names`` (when given) is the
+    set of manual axes."""
+    kw = {} if axis_names is None else {"axis_names": set(axis_names)}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False, **kw)

@@ -28,6 +28,7 @@ import os
 import platform as _platform
 import re
 import warnings
+from pathlib import Path
 from typing import Dict, Optional
 
 _DEVICE_COUNT_FLAG = "--xla_force_host_platform_device_count"
@@ -39,6 +40,28 @@ _GPU_FLAGS = {
     "--xla_gpu_enable_latency_hiding_scheduler": "true",
     "--xla_gpu_triton_gemm_any": "True",
 }
+
+
+# fixed, never temp- or time-derived: a later run of this checkout must
+# find what an earlier one cached
+_CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
+
+def use_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and
+    nothing is set here.  Otherwise the cache lives at the fixed
+    ``.jax_cache`` directory of this checkout (git-ignored).  Called by
+    entry points (``chip_smoke.py``, ``benchmarks/run.py``), never at
+    import and never by tests.
+    """
+    env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env_dir:
+        return env_dir
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(_CHECKOUT_CACHE_DIR))
+    return str(_CHECKOUT_CACHE_DIR)
 
 
 def _jax_initialized() -> bool:

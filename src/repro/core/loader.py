@@ -16,7 +16,8 @@ name from the registry:
                 jitted ``parse_blocks`` -> packed device buffers
     pallas      same pipeline, but parsing runs through
                 ``kernels.parse_edges.parse_edges_accumulate``
-                (the Mosaic kernel on TPU, its XLA twin elsewhere)
+                (its XLA twin; refused on a TPU backend, where
+                the kernel does not lower yet)
     numpy       single-pass vectorized numpy parser (host)
     threads     thread pool over newline-aligned chunks (host)
     snapshot    zero-parse mmap of a binary ``.gvel`` snapshot
@@ -314,6 +315,11 @@ def _parse_span(
     inline staging of batch i+1 already overlaps the async-dispatched
     device parse of batch i, without d extra threads).
     """
+    if parse == "pallas" and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "engine 'pallas': the kernels.parse_edges Pallas kernel does "
+            "not lower for TPU yet (Mosaic refuses its (1, buf_len) block "
+            "shape and has no cumsum lowering); use engine='device'")
     os_, oe = owned_range(plan)
     edge_cap = plan.edge_cap
     nspan = max(block_hi - block_lo, 0)

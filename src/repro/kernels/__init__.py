@@ -1,10 +1,11 @@
-"""Pallas TPU kernels for GVEL's compute hot spots.
+"""Pallas kernels for GVEL's compute hot spots.
 
 Each kernel package ships kernel.py (pl.pallas_call + explicit BlockSpec
 VMEM tiling), ops.py (jit'd wrapper with an XLA fallback), and ref.py
 (pure-jnp oracle used by the allclose test sweeps).
 
-  parse_edges       text block -> packed edges (GVEL Alg. 1 hot loop)
+  parse_edges       text block -> packed edges (GVEL Alg. 1 hot loop;
+                    interpret mode only, it does not lower for TPU yet)
   degree_histogram  contention-free degree counting (rho-partition analogue)
   exclusive_scan    degrees -> CSR offsets (Alg. 2 exclusiveScan)
   neighbor_gather   batched CSR row gather (sampler consumer of the CSR)

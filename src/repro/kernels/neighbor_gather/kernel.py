@@ -31,7 +31,7 @@ def _gather_body(u_ref, off_ref, tgt_ref, out_ref, deg_ref, *, width: int):
         deg = hi - lo
         # clamp the slice start so the fixed-width window stays in bounds
         start = jnp.minimum(lo, jnp.maximum(tgt_ref.shape[-1] - width, 0))
-        row = pl.load(tgt_ref, (pl.ds(start, width),))
+        row = tgt_ref[pl.ds(start, width)]
         lane = jax.lax.iota(I32, width)
         shifted = lo - start
         valid = (lane >= shifted) & (lane < shifted + jnp.minimum(deg, width))

@@ -1,10 +1,14 @@
-"""Pallas TPU kernel: edgelist text block -> per-byte parsed edges.
+"""Pallas kernel: edgelist text block -> per-byte parsed edges.
 
-The TPU realization of GVEL Algorithm 1's hot loop.  Each grid step DMAs
-one `buf_len`-byte block (GVEL's beta=256 KiB fits VMEM with large
-headroom — v5e VMEM is ~16 MiB and the working set here is ~12 bytes of
-i32 state per input byte, so beta<=1 MiB tiles are safe) and runs the
-mask/scan parse entirely in VMEM:
+GVEL Algorithm 1's hot loop written as a Pallas kernel.  It runs only
+in interpret mode: it does not lower for TPU yet.  Compiled for v5e,
+Mosaic refuses the ``(1, buf_len)`` block shape when ``nb > 1`` (a
+block's last two dims must divide by (8, 128) or equal the array's),
+and with ``nb == 1`` it fails on ``cumsum``, which has no Mosaic
+lowering; the body's ``cummax``/``cummin`` and dynamic gathers
+(``cum_dig[end_pos]``, ``tok_val[p0]``) would come next.  The loaders'
+TPU path is the XLA twin, ``core.parse._parse_block_bytes``.  Each grid
+step takes one `buf_len`-byte block and runs the mask/scan parse:
 
   byte classes -> token segmentation (cumsum) -> digit place values
   (sorted-segment algebra: cumulative max/min/sum + gathers) -> per-line
@@ -17,14 +21,13 @@ whose algebra this body mirrors operation for operation.  Compaction is
 deliberately *outside* the kernel: the fused loader path packs a whole
 batch with one scatter (``core.parse._compact_accumulate``) straight
 into the donated accumulators, and the standalone ``parse_edges`` entry
-compacts per block.  Keeping the kernel scatter-free means every op in
-the body is VPU-native (compare/select/scan along the minor axis) — no
-Mosaic dynamic-scatter support needed.
+compacts per block.  The body has no scatter, but its scans and
+gathers are what Mosaic refuses (above).
 
 `weighted` is a *Python-level* specialization parameter — the paper found
 (§4.1.6) that making the weighted flag a template parameter keeps the hot
 loop small enough to stay in the instruction cache; here each value of
-the flag produces a distinct, smaller Mosaic program, the same insight.
+the flag produces a distinct, smaller program, the same insight.
 """
 from __future__ import annotations
 

@@ -12,10 +12,12 @@ Two entries share the byte-domain kernel (``parse_bytes_kernel``):
   intermediates and the separate scatter-accumulate program of the old
   two-step pipeline never materialize.
 
-``use_kernel=None`` resolves per backend: the Mosaic kernel on TPU, the
-pure-jnp oracle (the identical algebra, compiled by XLA) elsewhere —
-interpret-mode Pallas is a debugging device, not a fast path, so CPU
-runs should never pay for it implicitly.
+The kernel runs only in Pallas interpret mode.  It does not lower for
+TPU yet: Mosaic refuses its ``(1, buf_len)`` block shape when a batch
+has more than one block, and has no ``cumsum`` lowering for its body.
+So :func:`parse_edges_accumulate` defaults to ``use_kernel=False``, the
+pure-jnp twin (the identical algebra, compiled by XLA), on every
+backend; the kernel runs only when a caller asks for it.
 """
 from __future__ import annotations
 
@@ -29,17 +31,13 @@ from .kernel import parse_bytes_kernel, parse_edges_kernel
 from .ref import parse_edges_ref
 
 
-def _default_use_kernel() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def parse_edges(bufs, owned_start: int, owned_end: int, *, weighted: bool = False,
                 base: int = 1, edge_cap: int | None = None,
                 use_kernel: bool = True, interpret: bool = True):
     """Parse (nb, buf_len) text blocks -> (src, dst, w, counts).
 
-    use_kernel=False falls back to the pure-jnp oracle (the XLA path used
-    when running off-TPU).
+    use_kernel=False runs the pure-jnp oracle (the XLA path) instead of
+    the interpret-mode kernel.
     """
     nb, buf_len = bufs.shape
     if edge_cap is None:
@@ -54,11 +52,11 @@ def parse_edges(bufs, owned_start: int, owned_end: int, *, weighted: bool = Fals
 
 def _fused_impl(acc_src, acc_dst, acc_w, total, bufs, owned, *,
                 weighted: bool, base: int, edge_bound: int, max_digits: int,
-                use_kernel: bool, interpret: bool):
+                use_kernel: bool):
     if use_kernel:
         valid, src, dst, w = parse_bytes_kernel(
             bufs, owned, weighted=weighted, base=base, max_digits=max_digits,
-            interpret=interpret)
+            interpret=True)
     else:
         fn = functools.partial(parse_core._parse_block_bytes,
                                weighted=weighted, base=base,
@@ -75,7 +73,7 @@ def _fused_jit(donate: bool):
     return jax.jit(
         _fused_impl,
         static_argnames=("weighted", "base", "edge_bound", "max_digits",
-                         "use_kernel", "interpret"),
+                         "use_kernel"),
         donate_argnums=(0, 1, 2) if donate else ())
 
 
@@ -84,8 +82,7 @@ def parse_edges_accumulate(acc_src, acc_dst, acc_w, total, bufs,
                            weighted: bool = False, base: int = 1,
                            edge_bound: int | None = None,
                            max_digits: int = 9,
-                           use_kernel: bool | None = None,
-                           interpret: bool | None = None,
+                           use_kernel: bool = False,
                            donate: bool | None = None):
     """Fused kernel parse + donated packed accumulation (one program).
 
@@ -93,19 +90,17 @@ def parse_edges_accumulate(acc_src, acc_dst, acc_w, total, bufs,
     (nb, buf_len) and writes the batch's edges into the packed
     accumulators at offset ``total``, returning the updated
     ``(acc_src, acc_dst, acc_w, total)``.  Donated inputs are consumed —
-    rebind, never reuse, the passed accumulators.
+    rebind, never reuse, the passed accumulators.  ``use_kernel=True``
+    parses in the Pallas kernel, in interpret mode (see the module
+    docstring).
     """
     nb, buf_len = bufs.shape
     if edge_bound is None:
         edge_bound = nb * (buf_len // 4 + 2)
-    if use_kernel is None:
-        use_kernel = _default_use_kernel()
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     if donate is None:
         donate = parse_core.donation_supported()
     owned = jnp.asarray([owned_start, owned_end], jnp.int32)
     return _fused_jit(bool(donate))(
         acc_src, acc_dst, acc_w, total, bufs, owned, weighted=weighted,
         base=base, edge_bound=edge_bound, max_digits=max_digits,
-        use_kernel=bool(use_kernel), interpret=bool(interpret))
+        use_kernel=bool(use_kernel))

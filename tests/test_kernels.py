@@ -96,7 +96,7 @@ def test_parse_edges_accumulate_matches_core(weighted, use_kernel):
     got = make_accumulators(cap, weighted=weighted)
     got = parse_edges_accumulate(*got, bufs, 0, n, weighted=weighted, base=1,
                                  edge_bound=bound, use_kernel=use_kernel,
-                                 interpret=True, donate=False)
+                                 donate=False)
     assert int(got[3]) == int(ref[3])
     assert np.array_equal(np.asarray(got[0]), np.asarray(ref[0]))
     assert np.array_equal(np.asarray(got[1]), np.asarray(ref[1]))

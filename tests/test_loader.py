@@ -12,7 +12,7 @@ from repro.core.generate import write_edgelist
 from repro.core.loader import _REGISTRY
 
 ENGINES = ["device", "numpy", "threads", "pallas"]
-# pallas runs the kernel in interpret mode — keep its inputs tiny
+# small blocks keep the streaming engines' compiles cheap
 SMALL_KW = {"device": dict(beta=4096, batch_blocks=2),
             "pallas": dict(beta=2048, batch_blocks=2)}
 
@@ -43,6 +43,17 @@ def test_register_engine_last_wins_and_dispatches(tmp_path):
         assert int(el.num_edges) == 1 and el.num_vertices == 9
     finally:
         _REGISTRY.pop("fake-test-engine", None)
+
+
+def test_pallas_engine_refuses_tpu_backend(tmp_path, monkeypatch):
+    """The Pallas parse kernel does not lower for TPU: on a TPU backend
+    the engine fails at its first call instead of running the XLA twin."""
+    import jax
+    path = tmp_path / "g.el"
+    path.write_text("1 2\n2 3\n")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(NotImplementedError, match="does not lower for TPU"):
+        load_csr(str(path), engine="pallas", beta=2048)
 
 
 # ---- engine parity vs host oracle -------------------------------------------
