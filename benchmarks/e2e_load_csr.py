@@ -8,7 +8,7 @@ as part of the frozen baseline), a device->host copy of every batch,
 ``np.concatenate``, a host EdgeList, and only then a device CSR build —
 all at the historical fixed geometry (beta=256 KiB, batch_blocks=8,
 padded tail batch).  The streaming path
-(``loader.load_csr(engine="device")``) double-buffers arena staging
+(``loader.load_csr(engine="device")``) double-buffers host staging
 behind one fused parse+accumulate program per batch (donated in-place
 accumulators, remainder-sized tail batch) that feeds the CSR build
 directly; the ``_tuned`` row additionally lets ``core.tune``'s measured

@@ -62,58 +62,13 @@ def flat_len(nb: int, plan: BlockPlan) -> int:
     return (nb - 1) * plan.beta + plan.buf_len
 
 
-class StagingArena:
-    """A ring of reusable flat staging buffers for the streaming loader.
+def _new_flat(nb: int, plan: BlockPlan) -> np.ndarray:
+    """A fresh newline-filled flat staging buffer for ``nb`` blocks.
 
-    Without an arena every staged batch allocates a fresh flat buffer
-    (and the allocator pays a page-fault walk over it).  The loader
-    instead creates one arena per stream and passes it to every
-    ``stage`` call; the per-batch host cost drops to a single memcpy of
-    the new bytes.
-
-    Ring discipline (why ``slots=2`` is safe): the loader double-buffers
-    — batch *i* is converted to a device array in the consuming thread
-    while batch *i+1* stages in the prefetch thread, so two buffers are
-    live at once.  A slot is only reused at batch *i+2*, which the
-    prefetch thread starts *after* the consumer finished with batch *i*
-    (``jnp.asarray`` of the strided view makes its contiguous copy
-    before the consumer submits more staging work).  Consumers that
-    hold staged views longer must pass more ``slots`` or copy.
-
-    Buffers are handed out dirty; the staging code newline-fills only
-    the head/tail slack it does not overwrite with file bytes.
-    """
-
-    def __init__(self, nbytes: int, slots: int = 2):
-        self._slots = [np.full(max(int(nbytes), 1), NEWLINE, np.uint8)
-                       for _ in range(max(int(slots), 2))]
-        self._turn = 0
-
-    def take(self, nbytes: int) -> np.ndarray:
-        """Next ring buffer, grown if needed; contents are stale."""
-        i = self._turn
-        self._turn = (self._turn + 1) % len(self._slots)
-        if self._slots[i].size < nbytes:
-            self._slots[i] = np.full(nbytes, NEWLINE, np.uint8)
-        return self._slots[i][:nbytes]
-
-
-def _take_flat(nb: int, plan: BlockPlan, arena: StagingArena | None,
-               filled_lo: int, filled_hi: int) -> np.ndarray:
-    """Flat staging buffer for ``nb`` blocks; everything outside
-    ``[filled_lo, filled_hi)`` (which the caller will overwrite with
-    file bytes) is newline-filled."""
-    need = flat_len(nb, plan)
-    if arena is None:
-        return np.full(need, NEWLINE, np.uint8)
-    flat = arena.take(need)
-    lo = max(min(filled_lo, need), 0)
-    hi = max(min(filled_hi, need), lo)
-    if lo:
-        flat[:lo] = NEWLINE
-    if hi < need:
-        flat[hi:] = NEWLINE
-    return flat
+    Fresh on every call: the streaming loader hands each staged batch to
+    a host-to-device transfer that may read (or, on the CPU backend,
+    alias) the bytes after it returns, so a buffer is never reused."""
+    return np.full(flat_len(nb, plan), NEWLINE, np.uint8)
 
 
 def _strided_block_view(flat: np.ndarray, nb: int, plan: BlockPlan) -> np.ndarray:
@@ -164,7 +119,6 @@ def check_line_overlap(view: np.ndarray, plan: BlockPlan,
 
 
 def stage_blocks(data: np.ndarray, plan: BlockPlan, block_ids: np.ndarray,
-                 arena: StagingArena | None = None,
                  check_lines: bool = False) -> np.ndarray:
     """Gather block buffers (with left overlap) into an (nb, buf_len) array.
 
@@ -175,9 +129,7 @@ def stage_blocks(data: np.ndarray, plan: BlockPlan, block_ids: np.ndarray,
     path: one contiguous memcpy of the spanned byte range into a
     newline-padded flat buffer, then a zero-copy strided window per
     block — the per-block Python loop this replaces copied the overlap
-    bytes twice and paid a numpy slice round-trip per block.  Passing an
-    ``arena`` reuses its ring buffers instead of allocating per batch
-    (see :class:`StagingArena` for the reuse discipline).
+    bytes twice and paid a numpy slice round-trip per block.
 
     ``check_lines=True`` (the text-parse pipelines set it; raw byte
     staging does not) raises ``ValueError`` when a line longer than
@@ -193,7 +145,7 @@ def stage_blocks(data: np.ndarray, plan: BlockPlan, block_ids: np.ndarray,
         lo = int(ids[0]) * plan.beta - plan.overlap        # may be < 0
         s = max(lo, 0)
         e = min(lo + flat_len(nb, plan), n)
-        flat = _take_flat(nb, plan, arena, s - lo, e - lo)
+        flat = _new_flat(nb, plan)
         if e > s:
             flat[s - lo : e - lo] = data[s:e]
         view = _strided_block_view(flat, nb, plan)
@@ -295,9 +247,8 @@ class MemoryBlockSource:
         self.length = len(data)
 
     def stage(self, plan: BlockPlan, block_ids: np.ndarray,
-              arena: StagingArena | None = None,
               check_lines: bool = False) -> np.ndarray:
-        return stage_blocks(self.data, plan, block_ids, arena, check_lines)
+        return stage_blocks(self.data, plan, block_ids, check_lines)
 
     def finish(self) -> None:
         pass
@@ -374,7 +325,6 @@ class SequentialBlockSource:
         return True
 
     def stage(self, plan: BlockPlan, block_ids: np.ndarray,
-              arena: StagingArena | None = None,
               check_lines: bool = False) -> np.ndarray:
         ids = np.asarray(block_ids, np.int64)
         nb = len(ids)
@@ -394,7 +344,7 @@ class SequentialBlockSource:
                 break                 # short stream: pad now, finish() raises
         s = max(lo, 0)
         e = min(hi, self._q_start + self._q_len)
-        flat = _take_flat(nb, plan, arena, s - lo, e - lo)
+        flat = _new_flat(nb, plan)
         pos = self._q_start           # walk the queue once, copying spans
         for view in self._q:
             if pos >= e:

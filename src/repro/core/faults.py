@@ -334,7 +334,7 @@ class FaultyBlockSource:
     source's cursor (``SequentialBlockSource`` advances ``_next_block``
     at entry) is untouched by an injected failure and the retried
     ``stage`` call is exact.  Data faults corrupt a copy of the staged
-    bytes (the arena buffer itself is never damaged).
+    bytes (the staged buffer itself is never damaged).
     """
 
     def __init__(self, inner, where: str):
@@ -346,16 +346,15 @@ class FaultyBlockSource:
     def length(self):
         return self._inner.length
 
-    def stage(self, plan, block_ids, arena=None, check_lines: bool = False):
+    def stage(self, plan, block_ids, check_lines: bool = False):
         ids = np.asarray(block_ids, dtype=np.int64)
         mutators: List[Tuple[FaultSpec, int]] = []
         for b in ids:
             for f in inject("block", int(b), where=self._where):
                 mutators.append((f, int(b)))
-        out = self._inner.stage(plan, block_ids, arena=arena,
-                                check_lines=check_lines)
+        out = self._inner.stage(plan, block_ids, check_lines=check_lines)
         if mutators:
-            out = np.array(out, copy=True)   # never damage the arena ring
+            out = np.array(out, copy=True)   # never damage the staged bytes
             for f, b in mutators:
                 row = int(np.nonzero(ids == b)[0][0])
                 raw = out[row].tobytes()

@@ -27,11 +27,12 @@ name from the registry:
 The device/pallas engines are *streaming* (GVEL's pipelined read):
 
   1. a host prefetch thread stages the next batch of overlap-padded
-     byte blocks (``blocks.stage_blocks``, through a reusable
-     :class:`~repro.core.blocks.StagingArena` — no per-batch
-     allocation) while the device parses the current one — read IO and
-     parse compute overlap, the madvise / double-buffer effect the
-     paper measures;
+     byte blocks (``blocks.stage_blocks``) while the device parses the
+     current one — read IO and parse compute overlap, the madvise /
+     double-buffer effect the paper measures.  Each batch is staged
+     into a host buffer of its own: a transfer may still read (or, on
+     the CPU backend, alias) the bytes it was handed after ``put``
+     returns, so nothing writes them again;
   2. each batch runs ONE jitted program (``parse.parse_accumulate``)
      that parses the blocks and writes the edges straight into packed
      device accumulators at the running offset, with the accumulator
@@ -79,8 +80,9 @@ import numpy as np
 from . import build
 from . import faults
 from . import parse as parse_mod
-from .blocks import StagingArena, flat_len, owned_range, plan_blocks
+from .blocks import owned_range, plan_blocks
 from .parse import donation_supported, parse_accumulate
+from .trace import span
 from .types import CSR, EdgeList
 
 I32 = jnp.int32
@@ -332,7 +334,6 @@ def _parse_span(
     def put(x):
         return jnp.asarray(x) if device is None else jax.device_put(x, device)
 
-    arena = StagingArena(flat_len(min(batch_blocks, nspan), plan))
     where = getattr(source, "_describe", None) or "block source"
 
     def batch_bytes(i: int) -> Tuple[int, int]:
@@ -344,14 +345,17 @@ def _parse_span(
     def stage(i: int) -> np.ndarray:
         start = block_lo + i * batch_blocks
         ids = np.arange(start, min(start + batch_blocks, block_hi))
-        # retries are safe here: injected faults fire before the source
-        # cursor moves, and raw (mmap) staging is idempotent.  A retry
-        # that still fails escalates to the shard/load level, where
-        # re-execution reopens the source from scratch.
-        return faults.call_with_retries(
-            lambda: source.stage(plan, ids, arena=arena, check_lines=True),
-            describe=f"{where}: stage blocks "
-                     f"[{int(ids[0])}, {int(ids[-1]) + 1})")
+        # every batch gets a fresh host buffer: once handed to `put` its
+        # bytes are never written again.  Retries are safe here: injected
+        # faults fire before the source cursor moves, and raw (mmap)
+        # staging is idempotent.  A retry that still fails escalates to
+        # the shard/load level, where re-execution reopens the source
+        # from scratch.
+        with span("load.stage"):
+            return faults.call_with_retries(
+                lambda: source.stage(plan, ids, check_lines=True),
+                describe=f"{where}: stage blocks "
+                         f"[{int(ids[0])}, {int(ids[-1]) + 1})")
 
     ostart = put(np.full((batch_blocks,), os_, np.int32))
     oend = put(np.full((batch_blocks,), oe, np.int32))
@@ -359,14 +363,16 @@ def _parse_span(
     def consume(i: int, bufs: np.ndarray) -> None:
         nonlocal acc_src, acc_dst, acc_w, total
         nb = bufs.shape[0]          # < batch_blocks on the tail batch
+        with span("load.put"):
+            dbufs = put(bufs)
         if parse == "pallas":
             from ..kernels import parse_edges_accumulate
             acc_src, acc_dst, acc_w, total = parse_edges_accumulate(
-                acc_src, acc_dst, acc_w, total, put(bufs), os_, oe,
+                acc_src, acc_dst, acc_w, total, dbufs, os_, oe,
                 weighted=weighted, base=base, edge_bound=nb * edge_cap)
         else:
             acc_src, acc_dst, acc_w, total = parse_accumulate(
-                acc_src, acc_dst, acc_w, total, put(bufs),
+                acc_src, acc_dst, acc_w, total, dbufs,
                 ostart[:nb], oend[:nb], weighted=weighted, base=base,
                 edge_bound=nb * edge_cap)
 
@@ -379,7 +385,8 @@ def _parse_span(
             fut = pool.submit(stage, 0)
             for i in range(num_batches):
                 try:
-                    bufs = fut.result(timeout=faults.WATCHDOG_S)
+                    with span("load.stage_wait"):
+                        bufs = fut.result(timeout=faults.WATCHDOG_S)
                 except _FutTimeout:
                     faults._count("stage_timeouts")
                     lo_b, hi_b = batch_bytes(i)
@@ -414,12 +421,11 @@ def _stream_edges(
     """File -> packed device edge buffers, double-buffered.
 
     Returns ((src, dst, w, total), capacity).  The prefetch thread stages
-    batch i+1 (into a reusable :class:`StagingArena` ring — one memcpy
-    per batch, no allocation) while the (async-dispatched) fused
-    parse+accumulate program works on batch i, so host staging overlaps
-    device compute.  The final short batch is *not* padded to
-    ``batch_blocks``: it runs a second, remainder-sized program, so a
-    2-block file parses 2 blocks, not ``batch_blocks``.
+    batch i+1 (into a host buffer of its own) while the
+    (async-dispatched) fused parse+accumulate program works on batch i,
+    so host staging overlaps device compute.  The final short batch is
+    *not* padded to ``batch_blocks``: it runs a second, remainder-sized
+    program, so a 2-block file parses 2 blocks, not ``batch_blocks``.
 
     Compressed inputs (``.el.gz`` / framed — sniffed by magic in
     :func:`codecs.open_block_source`) ride the same pipeline: the block
@@ -597,33 +603,40 @@ def read_csr_via(path: str, opts: LoadOptions, *,
         if num_vertices is None and hasattr(eng, "num_vertices_hint"):
             num_vertices = eng.num_vertices_hint(path)
         (src, dst, w, total), _cap = eng.stream(path, **opts.stream_kwargs())
-        n = int(total)
-        if num_vertices is None:
-            num_vertices = _device_num_vertices(src, dst) if n else 0
-        # Shrink the over-allocated buffers to the next power of two >= n
-        # before sorting: padding is all at the tail, so a prefix slice
-        # keeps every valid edge while bounding the sort size at 2n (and
-        # the pow-2 ladder bounds recompiles at log2(capacity) programs).
-        cap2 = 1 << max(n - 1, 1).bit_length()
-        if cap2 < src.shape[0]:
-            src, dst = src[:cap2], dst[:cap2]
-            w = w[:cap2] if weighted else None
-        if method == "global":
-            offsets, targets, ww = build.csr_global(
-                src, dst, w, num_vertices, weighted=weighted)
-        elif method == "staged":
-            offsets, targets, ww = build.csr_staged(
-                src, dst, w, num_vertices, rho=rho, weighted=weighted)
-        elif method == "binned":
-            offsets, targets, ww = build.csr_binned(
-                src, dst, w, num_vertices, bin_bits=bin_bits,
-                weighted=weighted)
-        else:
-            raise ValueError(f"unknown method {method!r}")
-        return CSR(np.asarray(offsets).astype(np.int64),
-                   np.asarray(targets[:n]),
-                   np.asarray(ww[:n]) if weighted else None,
-                   num_vertices)
+        with span("load.sync"):
+            n = int(total)
+            if num_vertices is None:
+                num_vertices = _device_num_vertices(src, dst) if n else 0
+        with span("load.build_dispatch"):
+            # Shrink the over-allocated buffers to the next power of two
+            # >= n before sorting: padding is all at the tail, so a prefix
+            # slice keeps every valid edge while bounding the sort size at
+            # 2n (and the pow-2 ladder bounds recompiles at log2(capacity)
+            # programs).  Rebinding drops this frame's hold on the full
+            # buffers before the build is dispatched.
+            cap2 = 1 << max(n - 1, 1).bit_length()
+            if cap2 < src.shape[0]:
+                src, dst = src[:cap2], dst[:cap2]
+                w = w[:cap2] if weighted else None
+            if method == "global":
+                offsets, targets, ww = build.csr_global(
+                    src, dst, w, num_vertices, weighted=weighted)
+            elif method == "staged":
+                offsets, targets, ww = build.csr_staged(
+                    src, dst, w, num_vertices, rho=rho, weighted=weighted)
+            elif method == "binned":
+                offsets, targets, ww = build.csr_binned(
+                    src, dst, w, num_vertices, bin_bits=bin_bits,
+                    weighted=weighted)
+            else:
+                raise ValueError(f"unknown method {method!r}")
+        with span("load.sync"):         # so the copy back is only the copy
+            jax.block_until_ready((offsets, targets, ww))
+        with span("load.copy_back"):
+            return CSR(np.asarray(offsets).astype(np.int64),
+                       np.asarray(targets[:n]),
+                       np.asarray(ww[:n]) if weighted else None,
+                       num_vertices)
     from .csr import convert_to_csr
     el = (fallback_edgelist() if fallback_edgelist is not None
           else read_edgelist_via(path, opts))

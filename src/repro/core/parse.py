@@ -59,6 +59,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .trace import span
+
 I32 = jnp.int32
 
 # byte classes
@@ -340,13 +342,10 @@ def make_accumulators(cap: int, *, weighted: bool, device=None):
     traffic).
     """
     cap = max(int(cap), 1)
-    acc_src = np.full((cap,), -1, np.int32)
-    acc_dst = np.full((cap,), -1, np.int32)
-    acc_w = np.zeros((cap,), np.float32) if weighted else None
-    total = np.zeros((), np.int32)
-    if device is None:
-        return (jnp.asarray(acc_src), jnp.asarray(acc_dst),
-                jnp.asarray(acc_w) if weighted else None, jnp.asarray(total))
-    put = functools.partial(jax.device_put, device=device)
-    return (put(acc_src), put(acc_dst), put(acc_w) if weighted else None,
-            put(total))
+    put = (jnp.asarray if device is None
+           else functools.partial(jax.device_put, device=device))
+    with span("load.accumulators"):
+        acc_src = put(np.full((cap,), -1, np.int32))
+        acc_dst = put(np.full((cap,), -1, np.int32))
+        acc_w = put(np.zeros((cap,), np.float32)) if weighted else None
+        return acc_src, acc_dst, acc_w, put(np.zeros((), np.int32))

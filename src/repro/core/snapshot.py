@@ -866,12 +866,15 @@ class SnapshotEngine:
         different files race only on which entry survives, never on a
         mixed key/value.
         """
+        from .trace import span
+
         st = os.stat(path)
         key = (path, st.st_mtime_ns, st.st_size)
         memo = self._memo
         if memo is not None and memo[0] == key:
             return memo[1]
-        snap = read_snapshot(path, eager=False)
+        with span("load.snapshot_read"):
+            snap = read_snapshot(path, eager=False)
         self._memo = (key, snap)
         return snap
 
@@ -918,22 +921,31 @@ class SnapshotEngine:
         """
         import jax.numpy as jnp
 
-        snap = self._snap(path)
-        self._check(snap, weighted=weighted, offset=offset)
-        if snap.num_edges > np.iinfo(np.int32).max:
-            # Same int32 regime as the text streaming engine's capacity
-            # guard: the fused path's running total is a device int32.
-            raise ValueError(
-                f"{path}: {snap.num_edges} edges exceeds int32 for the fused "
-                f"load_csr path; embed a prebuilt CSR in the snapshot "
-                f"(scripts/convert.py default) or use load_edgelist")
-        if not snap.has_edgelist:
-            raise SnapshotError(f"{snap.path}: CSR-only snapshot has no "
-                                f"edgelist sections")
-        src = jnp.asarray(snap.src)
-        dst = jnp.asarray(snap.dst)
-        w = jnp.asarray(snap.edge_weights) if weighted else None
-        total = jnp.asarray(snap.num_edges, jnp.int32)
+        from .trace import span
+
+        def put(section: np.ndarray):
+            with span("load.put"):
+                return jnp.asarray(section)
+
+        with span("load.snapshot_read"):
+            snap = self._snap(path)
+            self._check(snap, weighted=weighted, offset=offset)
+            if snap.num_edges > np.iinfo(np.int32).max:
+                # Same int32 regime as the text streaming engine's
+                # capacity guard: the fused path's running total is a
+                # device int32.
+                raise ValueError(
+                    f"{path}: {snap.num_edges} edges exceeds int32 for the "
+                    f"fused load_csr path; embed a prebuilt CSR in the "
+                    f"snapshot (scripts/convert.py default) or use "
+                    f"load_edgelist")
+            if not snap.has_edgelist:
+                raise SnapshotError(f"{snap.path}: CSR-only snapshot has no "
+                                    f"edgelist sections")
+            src = put(snap.src)
+            dst = put(snap.dst)
+            w = put(snap.edge_weights) if weighted else None
+            total = jnp.asarray(snap.num_edges, jnp.int32)
         return (src, dst, w, total), snap.num_edges
 
     def read_csr_prebuilt(self, path: str, *, weighted: bool = False,

@@ -52,6 +52,7 @@ from .loader import (DEFAULT_CSR_ENGINE, DEFAULT_EDGELIST_ENGINE, LoadOptions,
                      available_engines, csr_convert_engine, get_engine,
                      read_csr_sharded_via, read_csr_via, read_edgelist_via,
                      resolve_tuned)
+from .trace import span
 from .types import CSR, EdgeList
 
 FORMAT_GVEL = "gvel"
@@ -664,7 +665,8 @@ def open_graph(
                        num_vertices=num_vertices, offset=offset, tune=tune,
                        method=method, bin_bits=bin_bits, faults=faults,
                        engine_kw=dict(engine_kw))
-    return GraphSource(path, opts, validate=validate)
+    with span("load.open"):
+        return GraphSource(path, opts, validate=validate)
 
 
 def _main(argv: Optional[list] = None) -> int:

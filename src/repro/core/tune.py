@@ -8,7 +8,7 @@ statically.  This module replaces the loader's historical
 ``beta=256 KiB, batch_blocks=8`` magic numbers with the same idea:
 
 * :func:`run_sweep` stages a synthetic in-memory edgelist through the
-  *actual* fused streaming step (``StagingArena`` +
+  *actual* fused streaming step (``blocks`` staging +
   ``parse.parse_accumulate``) for every ``beta x batch_blocks`` combo
   and times it (compile excluded by a warmup pass per combo);
 * :func:`tuned_geometry` memoizes the sweep winner in a per-host JSON
@@ -96,8 +96,7 @@ def measure_geometry(data: np.ndarray, beta: int, batch_blocks: int, *,
     import jax
     import jax.numpy as jnp
 
-    from .blocks import (MemoryBlockSource, StagingArena, flat_len,
-                         owned_range, plan_blocks)
+    from .blocks import MemoryBlockSource, owned_range, plan_blocks
     from .parse import make_accumulators, parse_accumulate
 
     plan = plan_blocks(len(data), beta=beta, overlap=overlap)
@@ -105,7 +104,6 @@ def measure_geometry(data: np.ndarray, beta: int, batch_blocks: int, *,
     edge_cap = plan.edge_cap
     cap = plan.num_blocks * edge_cap
     num_batches = -(-plan.num_blocks // batch_blocks)
-    arena = StagingArena(flat_len(min(batch_blocks, plan.num_blocks), plan))
     source = MemoryBlockSource(data)
 
     def one_pass() -> None:
@@ -115,7 +113,7 @@ def measure_geometry(data: np.ndarray, beta: int, batch_blocks: int, *,
             start = i * batch_blocks
             ids = np.arange(start, min(start + batch_blocks,
                                        plan.num_blocks))
-            bufs = source.stage(plan, ids, arena=arena)
+            bufs = source.stage(plan, ids)
             nb = bufs.shape[0]
             acc_src, acc_dst, acc_w, total = parse_accumulate(
                 acc_src, acc_dst, acc_w, total, jnp.asarray(bufs),

@@ -23,7 +23,7 @@ Contract (tests/test_corpus.py, docs/serving.md):
 * **Prefetch-threaded, double-buffered**: ``batches()`` builds walk
   batch ``n+1`` (and stages it host->device) in a background thread
   while the consumer runs step ``n`` — the serving-side mirror of the
-  loader's prefetch/arena discipline, reusing
+  loader's prefetch thread, reusing
   :class:`repro.data.pipeline.Prefetcher`.
 * **Degradable**: per-walk keying in :mod:`repro.data.walks` means a
   batch-size cut keeps the surviving walks bitwise identical

@@ -1,6 +1,5 @@
-"""The fused/donated streaming accumulator, the staging arena, the
-overlong-line guard, the block-geometry autotuner, and the bench_diff
-perf gate.
+"""The fused/donated streaming accumulator, the overlong-line guard, the
+block-geometry autotuner, and the bench_diff perf gate.
 
 The load-bearing suite here is the bitwise parity matrix: the fused
 ``parse_accumulate`` path (one jitted program per batch, donated
@@ -21,9 +20,7 @@ import pytest
 import jax.numpy as jnp
 
 from repro.core import load_csr, open_graph
-from repro.core.blocks import (MemoryBlockSource, StagingArena, flat_len,
-                               owned_range, plan_blocks, stage_blocks,
-                               NEWLINE)
+from repro.core.blocks import owned_range, plan_blocks, stage_blocks, NEWLINE
 from repro.core.build import csr_np, csr_staged
 from repro.core.codecs import write_framed
 from repro.core.generate import write_edgelist
@@ -225,50 +222,6 @@ def test_loader_parity_when_donation_refused(tmp_path, monkeypatch):
     assert np.array_equal(with_donation.offsets, without.offsets)
     assert np.array_equal(with_donation.targets, without.targets)
     assert np.array_equal(with_donation.weights, without.weights)
-
-
-# ---------------------------------------------------------------------------
-# staging arena
-# ---------------------------------------------------------------------------
-
-def test_arena_consecutive_stages_not_aliased(tmp_path):
-    """Batch i is consumed while batch i+1 stages: the two staged views
-    must never share memory.  Slot reuse only comes back at batch i+2
-    (the ring), by which point the loader has copied batch i out."""
-    data = np.frombuffer(b"".join(f"{i} {i + 1}\n".encode()
-                                  for i in range(1, 4000)), np.uint8)
-    plan = plan_blocks(len(data), beta=1024, overlap=64)
-    arena = StagingArena(flat_len(2, plan))
-    source = MemoryBlockSource(data)
-    ids = [np.arange(0, 2), np.arange(2, 4), np.arange(4, 6)]
-    v0 = source.stage(plan, ids[0], arena=arena)
-    v0_copy = np.array(v0)
-    v1 = source.stage(plan, ids[1], arena=arena)
-    assert not np.shares_memory(v0, v1)
-    # staging batch 1 must not have clobbered batch 0's bytes
-    assert np.array_equal(v0, v0_copy)
-    v2 = source.stage(plan, ids[2], arena=arena)
-    assert np.shares_memory(v0, v2)        # ring of 2: slot reused
-    # and reuse still stages the right bytes
-    assert np.array_equal(np.array(v2), stage_blocks(data, plan, ids[2]))
-
-
-def test_arena_reuse_refills_padding(tmp_path):
-    """A dirty ring slot must not leak the previous batch's bytes into
-    the newline padding of a shorter/terminal batch."""
-    lines = b"".join(f"{i} {i}\n".encode() for i in range(100, 400))
-    data = np.frombuffer(lines, np.uint8)
-    plan = plan_blocks(len(data), beta=512, overlap=64)
-    arena = StagingArena(flat_len(2, plan))
-    source = MemoryBlockSource(data)
-    nb = plan.num_blocks
-    staged = []
-    for start in range(0, nb, 2):
-        ids = np.arange(start, min(start + 2, nb))
-        got = np.array(source.stage(plan, ids, arena=arena))
-        assert np.array_equal(got, stage_blocks(data, plan, ids)), start
-        staged.append(got)
-    assert len(staged) >= 3                # ring actually wrapped
 
 
 # ---------------------------------------------------------------------------
