@@ -20,9 +20,15 @@ branch-free and identical for every block, so one jitted program serves all.
 
 One per-byte core, :func:`_parse_block_bytes`, carries that algebra in
 *sorted-segment* form: token/line ids increase with byte position, so
-every per-token and per-line quantity is a cumulative max/sum plus a
-gather instead of a scatter — on CPU XLA a scatter runs ~5M elem/s
-while cumsum/gather run 20-100M elem/s.  Two entry points wrap it:
+every per-token and per-line quantity is a *fill* — a value carried
+from a marked byte to the bytes after (or before) it — made of
+cumulative max/min/sum scans and elementwise work, with no scatter and
+no gather.  Scatters were dropped first (on CPU XLA a scatter runs ~5M
+elem/s, a cumsum or gather 20-100M); then the gathers that had replaced
+them: on a TPU v5e a byte-domain gather costs about 8 ns a byte
+whatever its table's size (~17 ms a 2 MB batch), while the compiler
+runs each scan as a tiled reduce-window tree in under 1 ms a batch.
+Two entry points wrap it:
 
 * :func:`parse_block` / :func:`parse_blocks` — block in, fixed-capacity
   per-block ``(src, dst, w, count)`` out (one compaction scatter per
@@ -139,6 +145,40 @@ def parse_blocks(
 # fused parse -> accumulate (the streaming loader's hot path)
 # ---------------------------------------------------------------------------
 
+def _pow10(k, max_digits: int, dtype):
+    """``10 ** k`` for ``0 <= k <= max_digits``, elementwise: a ladder over
+    k's bits whose factors and partial products are exact powers of ten,
+    so float32 results are exact too (10^10 is)."""
+    p = jnp.ones(k.shape, dtype)
+    for b in range(max_digits.bit_length()):
+        p = jnp.where(((k >> b) & 1) == 1,
+                      p * jnp.asarray(10 ** (1 << b), dtype), p)
+    return p
+
+
+def _fill_forward(mark, value, ordinal, ord_max: int):
+    """Per byte ``i``: ``value`` at the latest marked byte ``<= i``, and
+    that byte's ``ordinal`` (-1, with a garbage value, before any mark).
+
+    ``ordinal`` must strictly increase from one marked byte to the next
+    and lie in ``[0, ord_max]``.  Each piece of the value's 32 bits rides
+    below the ordinal in one int32 ``cummax``, so the latest mark wins
+    every piece: a fill made of scans, with no gather.
+    """
+    piece = 31 - ord_max.bit_length()
+    if piece < 8:
+        raise ValueError(f"ordinals up to {ord_max} leave no room to fill: "
+                         "the block is too long")
+    mask = (1 << piece) - 1
+    bits = jax.lax.bitcast_convert_type(value, I32)
+    out = jnp.zeros_like(bits)
+    for shift in range(0, 32, piece):
+        part = jax.lax.shift_right_logical(bits, shift) & mask
+        key = jax.lax.cummax(jnp.where(mark, (ordinal << piece) | part, -1))
+        out = out | ((key & mask) << shift)
+    return jax.lax.bitcast_convert_type(out, value.dtype), key >> piece
+
+
 def _parse_block_bytes(buf, owned_start, owned_end, *, weighted: bool,
                        base: int, max_digits: int = 9):
     """Per-byte fused parse of one block: ``(valid, src, dst, w)`` in the
@@ -148,14 +188,18 @@ def _parse_block_bytes(buf, owned_start, owned_end, *, weighted: bool,
     a well-formed edge line; ``src``/``dst``/``w`` carry that line's
     parsed values at those bytes (garbage elsewhere — consumers gather
     at valid positions only).  Token/line ids increase with byte
-    position, so every per-token and per-line quantity is a cumulative
-    max/sum plus a gather — no scatters at all.  Integer token values
-    come from a wrapped int32 cumulative sum — per-token differences
-    are exact for <= ``max_digits`` digit tokens.  The Pallas kernel
-    (``kernels.parse_edges``) realizes this same algebra in VMEM; both
-    wrappers (:func:`parse_block`, :func:`parse_accumulate`) and the
-    kernel therefore agree bit-for-bit.
+    position, so every per-token and per-line quantity is a *fill*: a
+    value carried from a marked byte (a token's start or end, a newline)
+    to the bytes after it, or before it.  Each fill is cumulative scans
+    plus elementwise work (:func:`_fill_forward`) — no scatter and no
+    gather.  Integer token values come from a wrapped int32 cumulative
+    sum — per-token differences are exact for <= ``max_digits`` digit
+    tokens.  At valid bytes the outputs equal, bit for bit, those of the
+    gather form of this algebra that the Pallas kernel
+    (``kernels.parse_edges``) realizes.
     """
+    if max_digits > 9:
+        raise ValueError(f"max_digits={max_digits}: token values are int32")
     n = buf.shape[0]
     d = buf.astype(I32)
     idx = jnp.arange(n, dtype=I32)
@@ -173,62 +217,63 @@ def _parse_block_bytes(buf, owned_start, owned_end, *, weighted: bool,
     next_tok = jnp.concatenate([is_tok[1:], jnp.zeros((1,), bool)])
     tok_end = is_tok & ~next_tok
 
-    cum_ts = jnp.cumsum(tok_start.astype(I32))     # token starts <= i
+    # token starts <= i: my token's ordinal, distinct at token ends and
+    # at token starts, and at most (n + 1) // 2 (starts are 2 bytes apart)
+    cum_ts = jnp.cumsum(tok_start.astype(I32))
     cum_dig = jnp.cumsum(is_digit.astype(I32))     # digits <= i
+    fill = functools.partial(_fill_forward, ordinal=cum_ts,
+                             ord_max=(n + 1) // 2)
 
-    # my token's end/start byte position, per byte (valid at token bytes:
-    # tokens never span newlines, so runs are well-nested)
-    end_pos = jax.lax.cummin(jnp.where(tok_end, idx, n - 1), reverse=True)
-    start_pos = jax.lax.cummax(jnp.where(tok_start, idx, 0))
-
-    # digits strictly after byte i within its token
-    digits_after = jnp.clip(cum_dig[end_pos] - cum_dig, 0, max_digits)
-    pow10_i = 10 ** jnp.arange(max_digits + 1, dtype=I32)
-    contrib = jnp.where(is_digit, (d - 48) * pow10_i[digits_after], 0)
+    # digits strictly after byte i within its token: cum_dig never
+    # decreases, so its value at my token's end is a reverse cummin
+    dig_at_end = jax.lax.cummin(
+        jnp.where(tok_end, cum_dig, jnp.iinfo(np.int32).max), reverse=True)
+    digits_after = jnp.clip(dig_at_end - cum_dig, 0, max_digits)
+    contrib = jnp.where(is_digit,
+                        (d - 48) * _pow10(digits_after, max_digits, I32), 0)
     csum_c = jnp.cumsum(contrib)       # int32 wraps; per-token diff is exact
-    excl_c = csum_c - contrib
-    # integer value of the token ending at byte i (valid at token ends)
-    tok_val = csum_c - excl_c[start_pos]
+    # integer value of the token ending at byte i (valid at token ends):
+    # csum_c less its value just before my token's start
+    tok_val = csum_c - fill(tok_start, csum_c - contrib)[0]
 
-    # latest newline strictly before byte i (-1: none)
-    pex = jnp.concatenate([
-        jnp.full((1,), -1, I32),
-        jax.lax.cummax(jnp.where(is_nl, idx, -1))[:-1]])
-    # token starts up to my line's opening newline
-    cts_at = jnp.where(pex < 0, 0, cum_ts[jnp.maximum(pex, 0)])
-    # my token's 0-based ordinal within its line (valid at token ends)
+    # token starts up to my line's opening newline (cum_ts never
+    # decreases, so a cummax carries it; shifted: strictly before i)
+    cts_nl = jax.lax.cummax(jnp.where(is_nl, cum_ts, 0))
+    cts_at = jnp.concatenate([jnp.zeros((1,), I32), cts_nl[:-1]])
+    # my token's 0-based ordinal within its line (valid at token bytes)
     ord_in_line = cum_ts - 1 - cts_at
 
-    def role_pos(k):
-        """Latest byte <= i ending a token with line-ordinal k."""
-        return jax.lax.cummax(jnp.where(tok_end & (ord_in_line == k), idx, -1))
+    def role(k, value):
+        """``value`` at the latest token end with line-ordinal k, and
+        whether that token lies in byte i's line: it does iff its
+        ordinal passes the count of token starts before the line."""
+        got, ordinal = fill(tok_end & (ord_in_line == k), value)
+        return got, ordinal > cts_at
 
-    p0, p1 = role_pos(0), role_pos(1)
-    bad_pos = jax.lax.cummax(jnp.where(is_bad, idx, -1))
+    # is the latest newline-or-bad byte strictly before i a bad byte?
+    nl_bad = jax.lax.cummax(
+        jnp.where(is_nl | is_bad, 2 * idx + 2 + is_bad.astype(I32), 0))
+    bad_in_line = jnp.concatenate(
+        [jnp.zeros((1,), bool), (nl_bad[:-1] & 1) == 1])
 
+    src, _ = role(0, tok_val)
+    dst, has_dst = role(1, tok_val)
     owned = (idx >= owned_start) & (idx < owned_end)
     # ">= 2 tokens in the line" <=> a role-1 token ends inside it
-    valid = is_nl & owned & (p1 > pex) & ~(bad_pos > pex)
-
-    src = tok_val[jnp.maximum(p0, 0)] - base
-    dst = tok_val[jnp.maximum(p1, 0)] - base
+    valid = is_nl & owned & has_dst & ~bad_in_line
 
     w = None
     if weighted:
-        p2 = role_pos(2)
-        dot_pos = jax.lax.cummax(jnp.where(is_dot, idx, -1))
-        minus_pos = jax.lax.cummax(jnp.where(is_minus, idx, -1))
-        p2c = jnp.maximum(p2, 0)
-        w_start = start_pos[p2c]
-        dot_of = dot_pos[p2c]
-        frac_len = jnp.where(dot_of >= w_start,
-                             cum_dig[p2c] - cum_dig[jnp.maximum(dot_of, 0)], 0)
-        pow10_f = jnp.float32(10.0) ** jnp.arange(max_digits + 1)
-        wf = tok_val[p2c].astype(jnp.float32) \
-            / pow10_f[jnp.clip(frac_len, 0, max_digits)]
-        wf = jnp.where(minus_pos[p2c] >= w_start, -wf, wf)
-        w = jnp.where(p2 > pex, wf, 1.0)       # missing weight -> 1
-    return valid, src, dst, w
+        # a minus / a dot in my token: its token ordinal is mine
+        neg = jax.lax.cummax(jnp.where(is_minus, cum_ts, 0)) == cum_ts
+        has_dot = jax.lax.cummax(jnp.where(is_dot, cum_ts, 0)) == cum_ts
+        dig_at_dot = jax.lax.cummax(jnp.where(is_dot, cum_dig, 0))
+        frac_len = jnp.where(has_dot, cum_dig - dig_at_dot, 0)
+        wf = tok_val.astype(jnp.float32) / _pow10(
+            jnp.clip(frac_len, 0, max_digits), max_digits, jnp.float32)
+        wf, has_w = role(2, jnp.where(neg, -wf, wf))
+        w = jnp.where(has_w, wf, 1.0)          # missing weight -> 1
+    return valid, src - base, dst - base, w
 
 
 def _compact_accumulate(acc_src, acc_dst, acc_w, total, valid, src, dst, w,

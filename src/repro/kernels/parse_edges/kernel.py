@@ -16,8 +16,10 @@ step takes one `buf_len`-byte block and runs the mask/scan parse:
 
 The kernel emits the **byte domain**: ``valid[i]`` marks owned newlines
 terminating well-formed edge lines, with that line's (src, dst, w) at
-those bytes — the same contract as ``core.parse._parse_block_bytes``,
-whose algebra this body mirrors operation for operation.  Compaction is
+those bytes — the same contract as ``core.parse._parse_block_bytes``.
+This body looks values up at running max/min positions (gathers); the
+twin computes them as gather-free fills.  The two agree at every valid
+byte, so they match after compaction.  Compaction is
 deliberately *outside* the kernel: the fused loader path packs a whole
 batch with one scatter (``core.parse._compact_accumulate``) straight
 into the donated accumulators, and the standalone ``parse_edges`` entry

@@ -16,7 +16,7 @@ The kernel runs only in Pallas interpret mode.  It does not lower for
 TPU yet: Mosaic refuses its ``(1, buf_len)`` block shape when a batch
 has more than one block, and has no ``cumsum`` lowering for its body.
 So :func:`parse_edges_accumulate` defaults to ``use_kernel=False``, the
-pure-jnp twin (the identical algebra, compiled by XLA), on every
+pure-jnp twin (the same byte-domain values, compiled by XLA), on every
 backend; the kernel runs only when a caller asks for it.
 """
 from __future__ import annotations

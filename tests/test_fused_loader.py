@@ -124,7 +124,7 @@ def test_fused_engine_bitwise_equals_unfused(tmp_path, codec, weighted, base):
 @pytest.mark.parametrize("weighted,base", [(False, 1), (True, 0)])
 def test_pallas_engine_bitwise_equals_device(tmp_path, weighted, base):
     """Both streaming engines run the same fused-donated accumulate off
-    the same per-byte algebra; their CSR outputs must be identical."""
+    the same per-byte values; their CSR outputs must be identical."""
     path, v, e, _ = _graph(tmp_path, weighted=weighted, base=base, seed=21,
                            e=900)
     dev = load_csr(path, engine="device", weighted=weighted, base=base,

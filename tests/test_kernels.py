@@ -80,7 +80,7 @@ def test_parse_edges_hypothesis(nb, n, weighted, seed):
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_parse_edges_accumulate_matches_core(weighted, use_kernel):
     """The fused kernel path must match ``core.parse.parse_accumulate``
-    bit for bit — same per-byte algebra, same shared compaction."""
+    bit for bit — same per-byte values, same shared compaction."""
     from repro.core.parse import make_accumulators, parse_accumulate
     from repro.kernels import parse_edges_accumulate
 
