@@ -6,9 +6,8 @@ contention-free; across a mesh the same structure becomes:
   stage 0  every data shard parses its own byte range of the file
            (per-device edgelists == per-thread edgelists; pleasingly
            parallel, zero communication),
-  stage 1  shard-local partial degree histograms -> ``psum`` over the data
-           axis (the collective analogue of combining rho partition
-           degree arrays),
+  stage 1  a shard-local (sender, owner) bucket histogram, read to the
+           host, sizes the exchange's per-bucket capacity,
   stage 2  edges are bucketed by *owner* shard (vertex range partition)
            and exchanged with a single ``all_to_all`` — the only
            communication step, playing the role of the paper's merge,
@@ -31,7 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from . import build, compat
+from . import build, compat, trace
 from .types import CSR
 
 I32 = jnp.int32
@@ -221,7 +220,8 @@ def _exchange_build_fn(mesh: Mesh, axis: str, d: int, rows: int,
 
     lim = slice(None) if edge_limit is None else slice(None, edge_limit)
 
-    def body(s, dd, ww):
+    # named so that its module reads ``jit_exchange_build`` in a trace
+    def exchange_build(s, dd, ww):
         s, dd = s.reshape(-1)[lim], dd.reshape(-1)[lim]
         ww = ww.reshape(-1)[lim] if weighted else None
         rs, rd, rw, _, ovf = exchange_by_owner(
@@ -237,8 +237,8 @@ def _exchange_build_fn(mesh: Mesh, axis: str, d: int, rows: int,
     specs = P(axis)
     in_specs = (specs, specs, specs if weighted else P())
     out_specs = (P(axis), P(axis), P(axis), P(axis))
-    return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                    out_specs=out_specs))
+    return jax.jit(compat.shard_map(exchange_build, mesh=mesh,
+                                    in_specs=in_specs, out_specs=out_specs))
 
 
 def _shard_devices(mesh: Mesh, axis: str, e_per: int):
@@ -273,7 +273,7 @@ def stream_shards(
     overlap: Optional[int] = None,
     batch_blocks: Optional[int] = None,
     parse: str = "xla",
-) -> Tuple[jax.Array, jax.Array, Optional[jax.Array], list, int]:
+) -> Tuple[jax.Array, jax.Array, Optional[jax.Array], list]:
     """Stage 0, streamed: every shard parses its own byte range of the
     file through the fused donated pipeline, on its own device.
 
@@ -288,10 +288,10 @@ def stream_shards(
     thread per shard stages host bytes while its device parses, and the
     d device pipelines run concurrently.
 
-    Returns ``(src, dst, w, counts, max_vertex_id)``: global arrays of
-    ``d * e_per`` slots sharded on ``axis`` (assembled from the
-    per-device accumulators without any host round-trip), the per-shard
-    valid-edge counts, and the maximum vertex id seen (-1 when empty).
+    Returns ``(src, dst, w, counts)``: global arrays of ``d * e_per``
+    slots sharded on ``axis`` (assembled from the per-device
+    accumulators without any host round-trip) and the per-shard
+    valid-edge counts.
     """
     from concurrent.futures import ThreadPoolExecutor
     from concurrent.futures import TimeoutError as _FutTimeout
@@ -359,35 +359,35 @@ def stream_shards(
                         shard=k, fault_log=fault_log) from exc
                 faults_mod._count("shard_retries")
 
+    def join(k: int, fut):
+        try:
+            return fut.result(timeout=faults_mod.WATCHDOG_S)
+        except _FutTimeout:
+            faults_mod._count("stage_timeouts")
+            span = spans[k]
+            raise faults_mod.StageTimeout(
+                f"{path}: shard {k}/{d} produced nothing within "
+                f"the {faults_mod.WATCHDOG_S:.1f}s watchdog budget "
+                f"(REPRO_WATCHDOG_S) for byte span "
+                f"[{span.byte_lo}, {span.byte_hi}); the shard "
+                f"thread is stuck") from None
+
+    pool = None
     if d == 1:
         parts = [load_with_recovery(0)]
     else:
         # not a with-block: on a watchdog timeout the stuck shard thread
         # is abandoned (shutdown(wait=False)), never joined
         pool = ThreadPoolExecutor(d, thread_name_prefix="shard-load")
-        try:
-            futs = [pool.submit(load_with_recovery, k) for k in range(d)]
-            parts = []
-            for k, fut in enumerate(futs):
-                try:
-                    parts.append(fut.result(timeout=faults_mod.WATCHDOG_S))
-                except _FutTimeout:
-                    faults_mod._count("stage_timeouts")
-                    span = spans[k]
-                    raise faults_mod.StageTimeout(
-                        f"{path}: shard {k}/{d} produced nothing within "
-                        f"the {faults_mod.WATCHDOG_S:.1f}s watchdog budget "
-                        f"(REPRO_WATCHDOG_S) for byte span "
-                        f"[{span.byte_lo}, {span.byte_hi}); the shard "
-                        f"thread is stuck") from None
-        finally:
+    try:
+        with trace.span("load.shard_join"):
+            if pool is not None:
+                futs = [pool.submit(load_with_recovery, k) for k in range(d)]
+                parts = [join(k, fut) for k, fut in enumerate(futs)]
+            counts = [int(t) for (_, _, _, t) in parts]
+    finally:
+        if pool is not None:
             pool.shutdown(wait=False, cancel_futures=True)
-
-    counts = [int(t) for (_, _, _, t) in parts]
-    max_id = -1
-    for s, dd, _, _ in parts:
-        max_id = max(max_id, int(jnp.maximum(jnp.max(s, initial=-1),
-                                             jnp.max(dd, initial=-1))))
 
     def assemble(per_shard):
         arrays = []
@@ -399,10 +399,17 @@ def stream_shards(
         return jax.make_array_from_single_device_arrays(
             (d * e_per,), sharding, arrays)
 
-    src = assemble([p[0] for p in parts])
-    dst = assemble([p[1] for p in parts])
-    w = assemble([p[2] for p in parts]) if weighted else None
-    return src, dst, w, counts, max_id
+    with trace.span("load.assemble"):
+        src = assemble([p[0] for p in parts])
+        dst = assemble([p[1] for p in parts])
+        w = assemble([p[2] for p in parts]) if weighted else None
+    return src, dst, w, counts
+
+
+def _max_vertex_id(src: jax.Array, dst: jax.Array) -> int:
+    """The largest vertex id in the sharded edge buffers (-1 when they
+    hold no edge: padding slots are -1); one host sync."""
+    return int(jnp.maximum(jnp.max(src), jnp.max(dst)))
 
 
 def bucket_histogram(
@@ -433,7 +440,8 @@ def _bucket_histogram_fn(mesh: Mesh, axis: str, num_shards: int,
     :func:`_exchange_build_fn`."""
     lim = slice(None) if edge_limit is None else slice(None, edge_limit)
 
-    def body(s):
+    # named so that its module reads ``jit_bucket_histogram`` in a trace
+    def bucket_histogram(s):
         s = s.reshape(-1)[lim]
         owner = jnp.minimum(
             jnp.where(s >= 0, _owner(s, rows_per_shard), num_shards),
@@ -441,8 +449,8 @@ def _bucket_histogram_fn(mesh: Mesh, axis: str, num_shards: int,
         cnt = jnp.zeros((num_shards + 1,), I32).at[owner].add(1)
         return cnt[None, :num_shards]
 
-    return jax.jit(compat.shard_map(body, mesh=mesh, in_specs=P(axis),
-                                    out_specs=P(axis)))
+    return jax.jit(compat.shard_map(bucket_histogram, mesh=mesh,
+                                    in_specs=P(axis), out_specs=P(axis)))
 
 
 def load_csr_sharded_stream(
@@ -482,24 +490,27 @@ def load_csr_sharded_stream(
     Overflow is still detected and raised, so a hand-passed
     ``send_cap`` can never silently drop edges.
     """
-    src, dst, w, counts, max_id = stream_shards(
+    src, dst, w, counts = stream_shards(
         mesh, axis, path, weighted=weighted, base=base, offset=offset,
         beta=beta, overlap=overlap, batch_blocks=batch_blocks, parse=parse)
     if num_vertices is None:
-        num_vertices = max_id + 1
+        num_vertices = _max_vertex_id(src, dst) + 1
     d = mesh.shape[axis]
     rows = max(-(-num_vertices // d), 1)
     e_per = src.shape[0] // d
     edge_limit = min(e_per, _cap_round(max(counts, default=0)))
     if send_cap is None:
-        peak = int(bucket_histogram(mesh, axis, src, num_shards=d,
-                                    rows_per_shard=rows,
-                                    edge_limit=edge_limit).max())
+        with trace.span("load.bucket_histogram"):
+            peak = int(bucket_histogram(mesh, axis, src, num_shards=d,
+                                        rows_per_shard=rows,
+                                        edge_limit=edge_limit).max())
         send_cap = _cap_round(peak)
-    return load_csr_sharded(mesh, axis, src, dst, w,
-                            num_vertices=num_vertices, rho=rho,
-                            method=method, bin_bits=bin_bits,
-                            send_cap=send_cap, edge_limit=edge_limit)
+    with trace.span("load.exchange", shards=d, send_cap=send_cap,
+                    edge_limit=edge_limit, edges=sum(counts)):
+        return load_csr_sharded(mesh, axis, src, dst, w,
+                                num_vertices=num_vertices, rho=rho,
+                                method=method, bin_bits=bin_bits,
+                                send_cap=send_cap, edge_limit=edge_limit)
 
 
 def host_shard_and_load(

@@ -12,6 +12,10 @@ from __future__ import annotations
 import jax
 
 
-def span(name: str) -> jax.profiler.TraceAnnotation:
-    """Context manager marking the enclosed host work as ``name``."""
-    return jax.profiler.TraceAnnotation(name)
+def span(name: str, **stats) -> jax.profiler.TraceAnnotation:
+    """Context manager marking the enclosed host work as ``name``.
+
+    ``stats`` (whole numbers or strings) ride on the span as counters:
+    ``jax.profiler.ProfileData`` gives them back as the event's
+    ``stats``."""
+    return jax.profiler.TraceAnnotation(name, **stats)

@@ -413,3 +413,107 @@ assert calls[0] == 0, calls[0]
 print("COMPAT-OK")
 """
     assert "COMPAT-OK" in devices4(code)
+
+
+def test_graph500_sharded_load_matches_csr_np(devices4, tmp_path):
+    """A Graph500 graph (scale 12, 1-based text, about ten blocks a
+    shard) through ``open_graph(path, num_vertices=V).csr_sharded(mesh)``
+    equals ``csr_np`` of the generator's own edges, offsets and targets
+    exactly."""
+    code = _ORACLE_HELPERS + f"""
+from repro.core import open_graph
+from repro.core.compat import make_mesh
+from repro.core.generate import rmat_edges, write_edgelist
+
+src, dst, v = rmat_edges(12, 16, seed=22)
+path = r"{tmp_path}/g500.el"
+write_edgelist(path, src, dst, base=1)
+mesh = make_mesh((4,), ("data",))
+csr = open_graph(path, num_vertices=v, beta=1 << 14).csr_sharded(mesh)
+assert csr.num_vertices == v
+check_bitwise(csr, src, dst, None, v, 4)
+print("G500-OK")
+"""
+    assert "G500-OK" in devices4(code)
+
+
+def test_sharded_load_spans(devices4, tmp_path):
+    """In a profiler session the sharded load opens each of its four
+    spans once, and ``load.exchange`` carries the exchange's geometry:
+    the shard count, ``send_cap`` on the ``_cap_round`` ladder, the
+    scanned prefix ``edge_limit`` and the edges parsed (E)."""
+    code = f"""
+import collections, glob, json
+import jax
+from repro.core import open_graph
+from repro.core.compat import make_mesh
+from repro.core.distributed import _cap_round
+from repro.core.generate import rmat_edges, write_edgelist
+
+src, dst, v = rmat_edges(10, 16, seed=5)
+path = r"{tmp_path}/g.el"
+write_edgelist(path, src, dst, base=1)
+mesh = make_mesh((4,), ("data",))
+load = lambda: open_graph(path, num_vertices=v, beta=1 << 13).csr_sharded(mesh)
+load()                                   # compile outside the session
+opts = jax.profiler.ProfileOptions()
+opts.python_tracer_level = 0
+jax.profiler.start_trace(r"{tmp_path}/trace", profiler_options=opts)
+try:
+    jax.block_until_ready(load().targets)
+finally:
+    jax.profiler.stop_trace()
+(f,) = glob.glob(r"{tmp_path}/trace/**/*.xplane.pb", recursive=True)
+counts, stats = collections.Counter(), []
+for plane in jax.profiler.ProfileData.from_file(f).planes:
+    for line in plane.lines:
+        for ev in line.events:
+            if ev.name.startswith("load."):
+                assert plane.name.startswith("/host:"), plane.name
+                counts[ev.name] += 1
+                if ev.name == "load.exchange":
+                    stats.append(dict(ev.stats))
+for name in ("load.shard_join", "load.bucket_histogram", "load.exchange",
+             "load.assemble"):
+    assert counts[name] == 1, (name, counts)
+(st,) = stats
+assert set(st) == {{"shards", "send_cap", "edge_limit", "edges"}}, st
+assert st["shards"] == 4 and st["edges"] == len(src), st
+assert _cap_round(st["send_cap"]) == st["send_cap"], st
+assert 16 * st["send_cap"] >= st["edges"], st
+assert 4 * st["edge_limit"] >= st["edges"], st
+print("SPANS-OK", json.dumps(st))
+"""
+    assert "SPANS-OK" in devices4(code)
+
+
+def test_max_vertex_id_only_without_num_vertices(tmp_path, monkeypatch):
+    """The sharded load reads the largest vertex id off the devices only
+    when the caller gave no ``num_vertices``; either way the CSR equals
+    the oracle."""
+    from repro.core import build, distributed, open_graph
+    from repro.core.compat import make_mesh
+
+    calls = []
+    real = distributed._max_vertex_id
+    monkeypatch.setattr(distributed, "_max_vertex_id",
+                        lambda s, d: calls.append(1) or real(s, d))
+    rng = np.random.default_rng(4)
+    n, v = 1500, 113
+    src = rng.integers(0, v, n)
+    dst = rng.integers(0, v, n)
+    dst[7] = v - 1
+    path = tmp_path / "g.el"
+    path.write_text("\n".join(f"{s+1} {d+1}" for s, d in zip(src, dst)) + "\n")
+    oracle = build.csr_np(src, dst, None, v)
+    mesh = make_mesh((1,), ("data",))
+
+    for kw, want_calls in (({"num_vertices": v}, 0), ({}, 1)):
+        calls.clear()
+        csr = open_graph(str(path), beta=2048, **kw).csr_sharded(mesh)
+        assert len(calls) == want_calls, kw
+        assert csr.num_vertices == v
+        assert np.array_equal(np.asarray(csr.offsets)[0, :v + 1],
+                              np.asarray(oracle.offsets))
+        assert np.array_equal(np.asarray(csr.targets)[0, :n],
+                              np.asarray(oracle.targets))

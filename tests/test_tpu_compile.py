@@ -3,7 +3,8 @@
 Nothing here runs on a chip.  Each test lowers and compiles a program
 for a ``v5e:2x2`` topology that is described, not attached, at the
 geometry the loader uses on a Graph500 scale-22 text load: 256 KiB
-blocks, 8 blocks a batch, 2^27-slot edge buffers, 2^22 vertices.  A
+blocks, 8 blocks a batch, 2^27-slot edge buffers, 2^22 vertices; and
+the sharded load's exchange over the topology's four chips.  A
 program that the TPU compiler refuses, or that outgrows a v5e's HBM,
 fails here at no chip time.
 
@@ -17,10 +18,12 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import build, loader, parse
+from repro.core import build, distributed, loader, parse
 from repro.kernels.parse_edges.kernel import parse_bytes_kernel
 
 V5E_HBM_BYTES = 16 * 2**30
@@ -31,7 +34,7 @@ V = 1 << 22
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")      # else the compiler logs to /tmp
         from jax.experimental import topologies
@@ -40,7 +43,12 @@ def one_chip():
                                                 topology_name="v5e:2x2")
         except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 @pytest.fixture
@@ -93,6 +101,27 @@ def test_staged_build_compiles_for_v5e(one_chip, no_compile_cache):
     """The default CSR build at scale-22 capacity."""
     edges = _spec(one_chip, (CAP,), jnp.int32)
     compiled = build.csr_staged.lower(edges, edges, None, V, rho=4).compile()
+    assert _device_bytes(compiled.memory_analysis()) < V5E_HBM_BYTES
+
+
+def test_mesh_exchange_build_compiles_for_v5e_2x2(topo, no_compile_cache):
+    """The sharded load's exchange+build program over the four chips, at
+    a Graph500 scale-22 text load's geometry: 992 blocks a shard, 2^20
+    rows a chip, ``send_cap`` 3 * 2^21 and ``edge_limit`` 3 * 2^23 (the
+    ``_cap_round`` steps above E/16 edges a bucket and E/4 a shard)."""
+    d = 4
+    mesh = Mesh(np.array(topo.devices[:d]), ("data",))
+    e_per = 992 * (BUF_LEN // 4 + 2)
+    fn = distributed._exchange_build_fn(mesh, "data", d, V // d, 3 << 21,
+                                        4, False, 3 << 23)
+    edges = jax.ShapeDtypeStruct((d * e_per,), jnp.int32,
+                                 sharding=NamedSharding(mesh, P("data")))
+    no_w = jax.ShapeDtypeStruct((), jnp.float32,
+                                sharding=NamedSharding(mesh, P()))
+    compiled = fn.lower(edges, edges, no_w).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_exchange_build" in text
+    assert " all-to-all(" in text
     assert _device_bytes(compiled.memory_analysis()) < V5E_HBM_BYTES
 
 
