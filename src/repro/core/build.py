@@ -2,8 +2,9 @@
 
 There is no fetch-add on TPU, so PIGO's "claim a slot atomically" becomes a
 deterministic *rank*: edge e with source u lands at offsets[u] + (rank of e
-among u's edges).  Ranks come from a stable sort, which makes construction
-a pure gather/scatter with provably disjoint destinations.
+among u's edges).  Ranks come from a stable sort (and, in ``csr_staged``,
+the exclusive scan of the degree histogram), which makes construction a
+pure gather/scatter with provably disjoint destinations.
 
 * ``csr_global``    — one global stable sort over all edges
                       (single-stage; the PIGO-shaped baseline).
@@ -69,14 +70,6 @@ def _ceil_log2(n: int) -> int:
     return max(int(n - 1).bit_length(), 0)
 
 
-def _rank_in_group(sorted_key: jax.Array, num_vertices: int) -> jax.Array:
-    """rank of each sorted element within its equal-key run."""
-    first = jnp.searchsorted(sorted_key, jnp.arange(num_vertices + 1, dtype=I32),
-                             side="left")
-    return jnp.arange(sorted_key.shape[0], dtype=I32) - first[
-        jnp.clip(sorted_key, 0, num_vertices)]
-
-
 @functools.partial(jax.jit, static_argnames=("num_vertices", "weighted"))
 def csr_global(
     src: jax.Array,
@@ -110,12 +103,19 @@ def csr_staged(
 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
     """GVEL multi-stage build (Algorithm 2, rank-based).
 
-    Stage 1: rho contiguous edge partitions, each locally sorted by source
-             -> rho partition CSRs (vmapped: independent work).
+    Stage 1: rho contiguous edge partitions, each locally stable-sorted by
+             source, with its degree histogram -> rho partition CSRs
+             (vmapped: independent work).
     Stage 2: partition degrees -> global offsets + per-partition bases;
              every partition edge's destination =
              offsets[u] + (edges of u in earlier partitions) + local rank.
-    The scatter destinations are disjoint, so the merge is race-free.
+             The local rank of the edge at sorted position i is
+             i - first_p[u], where first_p[u], the start of u's run in
+             the sorted partition, is the exclusive scan of that
+             partition's degree histogram; so dest = T[p, u] + i with
+             one (rho, V) table T = offsets + before - first.
+    The scatter destinations are disjoint, so the merge is race-free, and
+    the stable sort keeps each row's targets in file order.
     """
     _check_offsets_width(src.shape[0])
     v = num_vertices
@@ -140,17 +140,18 @@ def csr_staged(
         order = jnp.argsort(keys, stable=True)
         skey = keys[order]
         deg = jnp.zeros((v,), I32).at[skey].add(1, mode="drop")
-        rank = _rank_in_group(skey, v)
-        return skey, dsts[order], deg, rank, ws[order]
+        return skey, dsts[order], deg, ws[order]
 
-    skey, sdst, pdeg, rank, sw = jax.vmap(local)(key, dstp, wp)
+    skey, sdst, pdeg, sw = jax.vmap(local)(key, dstp, wp)
 
     # ---- stage 2: global offsets + disjoint merge -------------------------
     deg = jnp.sum(pdeg, axis=0, dtype=I32)                       # (V,)
     offsets = jnp.concatenate([jnp.zeros((1,), I32), jnp.cumsum(deg, dtype=I32)])
     before = jnp.cumsum(pdeg, axis=0, dtype=I32) - pdeg          # (rho, V) excl
-    base = offsets[:-1][None, :] + before                        # (rho, V)
-    dest = jnp.take_along_axis(base, jnp.clip(skey, 0, v - 1), axis=1) + rank
+    first = jnp.cumsum(pdeg, axis=1, dtype=I32) - pdeg           # run starts
+    table = offsets[:-1][None, :] + before - first               # (rho, V)
+    dest = (jnp.take_along_axis(table, jnp.clip(skey, 0, v - 1), axis=1)
+            + jnp.arange(pcap, dtype=I32)[None, :])
     dest = jnp.where(skey < v, dest, e)                          # drop padding
     targets = jnp.full((e,), -1, I32).at[dest.reshape(-1)].set(
         sdst.reshape(-1), mode="drop")

@@ -204,3 +204,68 @@ def test_offsets_width_guard_under_limit_ok(monkeypatch):
     src, dst = _random_edges(v, 130, seed=2)
     o, t, _ = build.csr_binned(jnp.asarray(src), jnp.asarray(dst), None, v)
     assert int(o[-1]) == 130
+
+
+# ---- staged build, exact order -------------------------------------------------
+#
+# csr_staged's stable per-partition sort keeps each row's targets in file
+# order, so offsets, targets and weights must equal the stable-sort oracle
+# element for element — rows are not sorted before comparing.
+
+STAGED_CASES = ["interleaved_padding", "hub", "v1", "e_not_divisible",
+                "e_below_rho", "empty_end_rows", "weighted"]
+
+
+def _staged_case(case):
+    rng = np.random.default_rng(STAGED_CASES.index(case))
+    if case == "interleaved_padding":    # -1 slots scattered, as the exchange gives
+        v, e = 50, 1001
+        src = rng.integers(0, v, e).astype(np.int32)
+        dst = rng.integers(0, v, e).astype(np.int32)
+        hole = rng.random(e) < 0.2
+        src[hole] = -1
+        dst[hole] = -1
+    elif case == "hub":                  # every edge on one vertex
+        v, e = 32, 257
+        src = np.full(e, 7, np.int32)
+        dst = rng.integers(0, v, e).astype(np.int32)
+    elif case == "v1":
+        v, e = 1, 9
+        src = np.zeros(e, np.int32)
+        dst = np.zeros(e, np.int32)
+    elif case == "e_not_divisible":      # E % rho != 0 for every rho below
+        v, e = 7, 1001
+        src = rng.integers(0, v, e).astype(np.int32)
+        dst = rng.integers(0, v, e).astype(np.int32)
+    elif case == "e_below_rho":
+        v, e = 5, 3
+        src = np.asarray([4, 0, 4], np.int32)
+        dst = np.asarray([1, 2, 3], np.int32)
+    elif case == "empty_end_rows":       # no edge leaves vertex 0 or V-1
+        v, e = 100, 4096
+        src = rng.integers(1, v - 1, e).astype(np.int32)
+        dst = rng.integers(0, v, e).astype(np.int32)
+    else:                                # weighted, with padding at the tail
+        v, e = 64, 1000
+        src, dst = _random_edges(v, e, seed=17, pad=24)
+    w = _weights_for(src, seed=len(src)) if case == "weighted" else None
+    return v, src, dst, w
+
+
+@pytest.mark.parametrize("rho", [4, 7])
+@pytest.mark.parametrize("case", STAGED_CASES)
+def test_staged_exact_order_matches_oracle(case, rho):
+    v, src, dst, w = _staged_case(case)
+    ref = build.csr_np(src, dst, w, v)
+    o, t, ww = build.csr_staged(
+        jnp.asarray(src), jnp.asarray(dst),
+        None if w is None else jnp.asarray(w), v,
+        rho=rho, weighted=w is not None)
+    n = len(ref.targets)
+    assert np.array_equal(np.asarray(o, np.int64), np.asarray(ref.offsets))
+    assert np.array_equal(np.asarray(t)[:n], np.asarray(ref.targets))
+    assert np.all(np.asarray(t)[n:] == -1)    # padding slots stay unfilled
+    if case == "empty_end_rows":
+        assert ref.offsets[1] == 0 and ref.offsets[-2] == ref.offsets[-1]
+    if w is not None:
+        assert np.array_equal(np.asarray(ww)[:n], np.asarray(ref.weights))

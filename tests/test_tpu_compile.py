@@ -104,6 +104,19 @@ def test_staged_build_compiles_for_v5e(one_chip, no_compile_cache):
     assert _device_bytes(compiled.memory_analysis()) < V5E_HBM_BYTES
 
 
+def test_staged_build_has_no_search_for_v5e(one_chip, no_compile_cache):
+    """The build at 2^24 edges, 2^20 vertices, rho 4 takes each edge's
+    slot from the partition degree scan, with no binary search (a
+    ``while`` loop over V + 1 queries).  XLA keeps copy loops of its
+    own, so this looks for the op name, not for ``while``."""
+    edges = _spec(one_chip, (1 << 24,), jnp.int32)
+    compiled = build.csr_staged.lower(edges, edges, None, 1 << 20,
+                                      rho=4).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_csr_staged" in text
+    assert "searchsorted" not in text
+
+
 def test_mesh_exchange_build_compiles_for_v5e_2x2(topo, no_compile_cache):
     """The sharded load's exchange+build program over the four chips, at
     a Graph500 scale-22 text load's geometry: 992 blocks a shard, 2^20
@@ -122,6 +135,7 @@ def test_mesh_exchange_build_compiles_for_v5e_2x2(topo, no_compile_cache):
     text = compiled.as_text()
     assert "HloModule jit_exchange_build" in text
     assert " all-to-all(" in text
+    assert "searchsorted" not in text           # the local build scans
     assert _device_bytes(compiled.memory_analysis()) < V5E_HBM_BYTES
 
 
