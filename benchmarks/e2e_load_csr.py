@@ -35,14 +35,6 @@ CSR sections at open, while ``open_graph(path).csr()`` decodes only
 the CSR sections (per-section lazy decompression, this PR's ROADMAP
 item).
 
-The build row (``e2e.csr_build_binned``) isolates the CSR build on the
-loader-shaped packed device arrays (parse excluded): the sort-free
-binned build (``build.csr_binned``, propagation-blocking-style
-cumulative-count ranks) vs the rank-based staged build it replaces as
-the fast path.  Its ``speedup`` field is staged/binned — not the
-baseline axis — so the verify.sh floor pins "binned never slower than
-staged" directly.
-
 The sharded rows measure the byte-range-sharded streaming load
 (``core.distributed.load_csr_sharded_stream`` /
 ``GraphSource.csr_sharded``) at d=2 and d=4, in one subprocess forced
@@ -139,7 +131,7 @@ def _batch_roundtrip_csr(path, v, *, beta=256 * 1024, overlap=64,
         total += n
     el = EdgeList(np.concatenate(chunks_src), np.concatenate(chunks_dst),
                   None, np.int64(total), v)
-    return convert_to_csr(el, method="staged", rho=4)
+    return convert_to_csr(el)
 
 
 def _snapshots(path, v):
@@ -152,7 +144,7 @@ def _snapshots(path, v):
         el = load_edgelist(path, engine="numpy", num_vertices=v)
         save_snapshot(el_snap, edgelist=el)
         save_snapshot(csr_snap, edgelist=el,
-                      csr=convert_to_csr(el, method="staged", rho=4))
+                      csr=convert_to_csr(el))
     return el_snap, csr_snap
 
 
@@ -171,38 +163,13 @@ def _compressed(path, v):
     if not os.path.exists(zsnap):
         el = load_edgelist(path, engine="numpy", num_vertices=v)
         save_snapshot(zsnap, edgelist=el,
-                      csr=convert_to_csr(el, method="staged", rho=4),
+                      csr=convert_to_csr(el),
                       compress="zlib")
     return gz, fz, zsnap
 
 
 def _mb(path):
     return f"mb={os.path.getsize(path) / 1e6:.2f}"
-
-
-def _build_times(path, v, repeat):
-    """(staged, binned) build-only seconds on the same packed device
-    arrays the streaming loader hands the build — loader-shaped input
-    (pow-2 capacity, ``-1`` padding), parse excluded, so the row
-    isolates the CSR build the binned method replaces."""
-    import jax
-    import jax.numpy as jnp
-    from repro.core import load_edgelist
-    from repro.core.build import csr_binned, csr_staged
-
-    el = load_edgelist(path, engine="numpy", num_vertices=v)
-    n = int(el.num_edges)
-    cap = 1 << max(n - 1, 1).bit_length()
-    src = np.full(cap, -1, np.int32)
-    dst = np.full(cap, -1, np.int32)
-    src[:n] = np.asarray(el.src[:n])
-    dst[:n] = np.asarray(el.dst[:n])
-    bsrc, bdst = jnp.asarray(src), jnp.asarray(dst)
-    t_staged = timeit(lambda: jax.block_until_ready(
-        csr_staged(bsrc, bdst, None, v, rho=4)), repeat=repeat)
-    t_binned = timeit(lambda: jax.block_until_ready(
-        csr_binned(bsrc, bdst, None, v)), repeat=repeat)
-    return t_staged, t_binned
 
 
 _SHARDED_CODE = """
@@ -266,16 +233,16 @@ def run(quick: bool = False, json_path: str = None):
     gz, fz, zsnap = _compressed(path, v)
     snap_eng = get_engine("snapshot")
 
-    def cold(p, **kw):
+    def cold(p):
         # measure a fresh open (validation + any decompression), not a
         # hit on the engine's stat-validated in-process memo; every row
         # goes through the GraphSource front door
         snap_eng.clear_memo()
-        return open_graph(p, engine="snapshot", num_vertices=v).csr(**kw)
+        return open_graph(p, engine="snapshot", num_vertices=v).csr()
 
     def stream_csr(p, **kw):
         return open_graph(p, engine="device",
-                          num_vertices=v, **kw).csr(method="staged")
+                          num_vertices=v, **kw).csr()
 
     def eager_zsnap_csr():
         # the pre-GraphSource contract: read_snapshot() decompresses and
@@ -289,7 +256,7 @@ def run(quick: bool = False, json_path: str = None):
     # verify.sh never pays a tuning sweep
     t_tuned = None if quick else timeit(
         lambda: stream_csr(path, tune=True), repeat=repeat)
-    t_sel = timeit(lambda: cold(el_snap, method="staged"), repeat=repeat)
+    t_sel = timeit(lambda: cold(el_snap), repeat=repeat)
     t_scsr = timeit(lambda: cold(csr_snap), repeat=repeat)
     t_gz = timeit(lambda: stream_csr(gz), repeat=repeat)
     t_fz = timeit(lambda: stream_csr(fz), repeat=repeat)
@@ -325,18 +292,6 @@ def run(quick: bool = False, json_path: str = None):
         f"edges_per_s={e / t_zeager:.3e}")
     row("e2e.load_csr_snapshot_zlib_lazy", t_zlazy, zsnap,
         f"edges_per_s={e / t_zlazy:.3e};vs_eager={t_zeager / t_zlazy:.2f}x")
-    # build-only row: binned vs staged on the loader-shaped packed
-    # arrays.  Unlike the load rows, speedup here is staged/binned — the
-    # verify.sh floor (>= 1.0) pins the binned build to never regress
-    # behind the staged build it's meant to beat.
-    t_staged_b, t_binned_b = _build_times(path, v, repeat)
-    emit("e2e.csr_build_binned", t_binned_b,
-         f"edges_per_s={e / t_binned_b:.3e};"
-         f"vs_staged={t_staged_b / t_binned_b:.2f}x;" + _mb(path))
-    rows.append({"name": "e2e.csr_build_binned",
-                 "seconds": round(t_binned_b, 6),
-                 "mb": round(os.path.getsize(path) / 1e6, 3),
-                 "speedup": round(t_staged_b / t_binned_b, 2)})
     # sharded rows: speedup is vs the batch-roundtrip baseline like every
     # other row, chained through the same-split streaming re-timing so
     # the subprocess threadpool split is normalized out (module docstring)

@@ -3,7 +3,6 @@
   edgelist            read per-block Edgelists only
   degree-global       + degrees into one shared accumulator
   degree-partition4   + degrees into rho=4 partition accumulators
-  csr-global          + single-stage CSR (one global sort)
   csr-partition4      + staged CSR (GVEL: 4 local sorts + disjoint merge)
 """
 import jax.numpy as jnp
@@ -29,17 +28,12 @@ def run():
         degrees.combine_degrees(
             degrees.degrees_partitioned(src, v, 4)).block_until_ready()
 
-    def csr_global():
-        o, t, _ = build.csr_global(src, dst, None, v)
-        t.block_until_ready()
-
     def csr_staged():
         o, t, _ = build.csr_staged(src, dst, None, v, rho=4)
         t.block_until_ready()
 
     for name, extra in [("degree-global", deg_global),
                         ("degree-partition4", deg_part),
-                        ("csr-global", csr_global),
                         ("csr-partition4", csr_staged)]:
         t_extra = timeit(extra)
         total = t_read + t_extra

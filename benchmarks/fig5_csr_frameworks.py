@@ -25,7 +25,7 @@ def run():
 
         def gvel():
             el = read_edgelist_numpy(path, num_vertices=v)
-            convert_to_csr(el, method="staged", rho=4, engine="numpy")
+            convert_to_csr(el, engine="numpy")
 
         t_n = timeit(naive, repeat=1, warmup=0)
         t_p = timeit(pigo)

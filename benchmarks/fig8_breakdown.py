@@ -10,8 +10,7 @@ def run():
         el = load_edgelist(path, engine="numpy", num_vertices=v)
         t_el = timeit(lambda: load_edgelist(path, engine="numpy",
                                             num_vertices=v))
-        t_c = timeit(lambda: convert_to_csr(el, method="staged", rho=4,
-                                            engine="numpy"))
+        t_c = timeit(lambda: convert_to_csr(el, engine="numpy"))
         emit(f"fig8.{ds}.edgelist", t_el,
              f"share={t_el / (t_el + t_c) * 100:.0f}%")
         emit(f"fig8.{ds}.to_csr", t_c,

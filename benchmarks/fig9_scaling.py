@@ -1,9 +1,9 @@
 """Paper Fig. 9: strong scaling with worker count (1..16) and, for the
 sharded streaming loader, with mesh shard count (1, 2, 4).
 
-Workers are threads over newline-aligned chunks (reading) and over
-partition-local sorts (CSR build) — numpy's C kernels release the GIL,
-so on a multicore host this scales like the paper's OpenMP loops.  The
+Workers are threads over newline-aligned chunks (reading) — numpy's C
+kernels release the GIL, so on a multicore host this scales like the
+paper's OpenMP loops.  The
 shard sweep times ``core.distributed.load_csr_sharded_stream`` over
 meshes of 1, 2 and 4 forced host devices inside one subprocess (the
 device count is fixed at 4 so XLA's threadpool split is identical
@@ -18,8 +18,6 @@ import json
 import os
 import subprocess
 import sys
-
-import numpy as np
 
 from .common import dataset, emit, require_cpu_backend, timeit
 
@@ -63,32 +61,16 @@ def _shard_sweep(path, v):
 
 def run():
     from repro.core import load_edgelist
-    from repro.core.build import csr_binned_np, csr_staged_np
 
     path, v, e = dataset("web_rmat")
     cores = os.cpu_count()
-    el = load_edgelist(path, engine="threads", num_vertices=v, num_workers=1)
-    n = int(el.num_edges)
-    src = np.asarray(el.src[:n])
-    dst = np.asarray(el.dst[:n])
-
-    base_el = base_csr = base_bin = None
+    base_el = None
     for w in [1, 2, 4, 8, 16]:
         t_el = timeit(lambda ww=w: load_edgelist(
             path, engine="threads", num_vertices=v, num_workers=ww), repeat=2)
-        t_csr = timeit(lambda ww=w: csr_staged_np(
-            src, dst, None, v, rho=max(4, ww), num_workers=ww), repeat=2)
-        t_bin = timeit(lambda ww=w: csr_binned_np(
-            src, dst, None, v, num_workers=ww), repeat=2)
         base_el = base_el or t_el
-        base_csr = base_csr or t_csr
-        base_bin = base_bin or t_bin
         emit(f"fig9.edgelist_w{w}", t_el,
              f"speedup={base_el / t_el:.2f}x;cores_available={cores}")
-        emit(f"fig9.csr_w{w}", t_csr,
-             f"speedup={base_csr / t_csr:.2f}x;cores_available={cores}")
-        emit(f"fig9.csr_binned_w{w}", t_bin,
-             f"speedup={base_bin / t_bin:.2f}x;cores_available={cores}")
 
     sweep = _shard_sweep(path, v)
     base = sweep["d1"]

@@ -51,7 +51,7 @@ def _corpus(quick):
     raw0, z0 = path + ".qraw.gvel", path + ".qz.gvel"
     if not (os.path.exists(raw0) and os.path.exists(z0)):
         el = load_edgelist(path, engine="numpy", num_vertices=v)
-        csr = convert_to_csr(el, method="staged", rho=4)
+        csr = convert_to_csr(el)
         save_snapshot(raw0, edgelist=el, csr=csr)
         save_snapshot(z0, edgelist=el, csr=csr, compress="zlib")
     paths = [raw0, z0]

@@ -46,7 +46,7 @@ def _snapshot(quick):
     gv = path + ".serve.gvel"
     if not os.path.exists(gv):
         el = load_edgelist(path, engine="numpy", num_vertices=v)
-        csr = convert_to_csr(el, method="staged", rho=4)
+        csr = convert_to_csr(el)
         save_snapshot(gv, edgelist=el, csr=csr)
     return gv, v, e
 

@@ -108,7 +108,7 @@ def run_one_chip(scale: int) -> None:
     say(f"setup: edgelist-only snapshot {os.path.getsize(snap)} bytes "
         f"written in {time.perf_counter() - t0:.1f} s")
     t0 = time.perf_counter()
-    csr = open_graph(snap).csr(method="staged")
+    csr = open_graph(snap).csr()
     jax.block_until_ready((csr.offsets, csr.targets))
     dt = time.perf_counter() - t0
     say(f"smoke timing, not a benchmark: .gvel edgelist load_csr: {dt:.3f} s "

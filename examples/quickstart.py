@@ -38,7 +38,7 @@ def main():
           f"{t_el*1e3:.0f} ms ({int(el.num_edges)/t_el/1e6:.2f} M edges/s)")
 
     t0 = time.perf_counter()
-    csr = src.csr(method="staged", rho=4)    # fused streaming device build
+    csr = src.csr()                          # fused streaming device build
     t_c = time.perf_counter() - t0
     assert int(csr.offsets[-1]) == e
     print(f"csr() end-to-end (streaming device engine): {t_c*1e3:.0f} ms; "

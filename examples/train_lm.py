@@ -52,7 +52,7 @@ def main():
     path = os.path.join(tmp, "corpus.el")
     v, e = make_graph_file(path, "rmat", scale=13, edge_factor=16)
     t0 = time.perf_counter()
-    csr = read_csr(path, num_vertices=v, method="staged", engine="numpy")
+    csr = read_csr(path, num_vertices=v, engine="numpy")
     print(f"GVEL: loaded |V|={v:,} |E|={e:,} to CSR in "
           f"{time.perf_counter()-t0:.2f}s")
 

@@ -49,10 +49,6 @@ def main(argv=None) -> int:
                     "numpy; see repro.core.available_engines())")
     ap.add_argument("--no-csr", action="store_true",
                     help="store only the packed edgelist, not a prebuilt CSR")
-    ap.add_argument("--method", default="staged", choices=("staged", "global"),
-                    help="CSR build strategy for the embedded CSR")
-    ap.add_argument("--rho", type=int, default=4,
-                    help="partitions for the staged CSR build")
     ap.add_argument("--compress", default=None, metavar="CODEC[:LEVEL]",
                     help="store sections compressed (.gvel v2): zlib always, "
                     "zstd when the zstandard package is installed; e.g. "
@@ -91,7 +87,7 @@ def main(argv=None) -> int:
                              symmetric=args.symmetric, base=args.base,
                              num_vertices=args.num_vertices)
         out = src.save(args.output, compress=args.compress,
-                       csr=not args.no_csr, method=args.method, rho=args.rho)
+                       csr=not args.no_csr)
         # eager re-read of what we just wrote: decompress + CRC-check
         # every section now, not lazily at some consumer's first access
         from repro.core import read_snapshot

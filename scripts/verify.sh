@@ -80,13 +80,9 @@ PY
 # shows up here at ~0.14x).  Floors only — quick-run speedups are not
 # comparable to the committed full-run rows, so tolerance mode is for
 # full-vs-full diffs across PRs (see scripts/bench_diff.py).
-# ...and the binned CSR build must stay at least as fast as the staged
-# build it fronts (its speedup field is staged/binned, not the baseline
-# axis — see benchmarks/e2e_load_csr.py).
 python scripts/bench_diff.py BENCH_e2e.json /tmp/BENCH_e2e_quick.json \
     --require-only --require 'e2e.load_csr_streaming>=1.0' \
-    --require 'e2e.load_csr_sharded_d4>=1.0' \
-    --require 'e2e.csr_build_binned>=1.0'
+    --require 'e2e.load_csr_sharded_d4>=1.0'
 
 # query-service smoke + gate: thousands of mixed point/range/full
 # requests through the hot-graph cache (tests/test_query.py and

@@ -207,7 +207,6 @@ class SourceCache:
             self._faults["open_retries"] += 1
 
     def query(self, path: str, op: str, *, rows=None, vertex=None,
-              method: str = "staged", rho: int = 4,
               with_weights: bool = False, **open_kw) -> Any:
         """One request against the cache.  ``op`` selects the product:
 
@@ -235,11 +234,11 @@ class SourceCache:
             if op == "info":
                 return src.info()
             if op in ("csr", "full"):
-                return src.csr(method=method, rho=rho)
+                return src.csr()
             if op in ("rows", "csr_rows", "range"):
                 if rows is None:
                     raise ValueError("op 'rows' needs rows=")
-                return src.csr(method=method, rho=rho, rows=rows)
+                return src.csr(rows=rows)
             if op in ("neighbors", "point"):
                 if vertex is None:
                     raise ValueError("op 'neighbors' needs vertex=")

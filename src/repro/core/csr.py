@@ -1,9 +1,8 @@
 """End-to-end CSR reading: file -> EdgeList -> CSR (GVEL csr-partition-rho).
 
-``convert_to_csr`` exposes the strategy ladder measured in the paper's
-Figure 3/4 (csr-global vs csr-partition-k); ``read_csr`` composes a reader
-with a converter and optionally *fuses* degree counting into the read loop,
-the analogue of GVEL counting degrees while parsing (Alg. 1 line 25).
+``convert_to_csr`` turns an in-memory EdgeList into a CSR with the staged
+build (``build.csr_staged``) or, on the host, the numpy oracle;
+``read_csr`` is the file -> CSR wrapper over the unified loader.
 """
 from __future__ import annotations
 
@@ -12,26 +11,15 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
-from . import build, degrees
+from . import build
 from .types import CSR, EdgeList
 
 
-def convert_to_csr(
-    el: EdgeList,
-    *,
-    method: str = "staged",
-    rho: int = 4,
-    bin_bits: Optional[int] = None,
-    engine: str = "jax",
-) -> CSR:
+def convert_to_csr(el: EdgeList, *, engine: str = "jax") -> CSR:
     """Convert an in-memory EdgeList to CSR.
 
-    method: 'global' (single-stage baseline) | 'staged' (GVEL, rho
-    partitions) | 'binned' (propagation-blocking bins of 2**bin_bits
-    vertices)
-    engine: 'jax' | 'numpy'
+    engine: 'jax' (the staged device build) | 'numpy' (the host oracle)
     """
-    method = method or "staged"
     n = int(el.num_edges)
     v = el.num_vertices
     weighted = el.weights is not None
@@ -39,23 +27,11 @@ def convert_to_csr(
         s = np.asarray(el.src[:n])
         d = np.asarray(el.dst[:n])
         w = None if not weighted else np.asarray(el.weights[:n])
-        if method == "binned":
-            return build.csr_binned_np(s, d, w, v, bin_bits=bin_bits)
         return build.csr_np(s, d, w, v)
     src = jnp.asarray(el.src[:n])
     dst = jnp.asarray(el.dst[:n])
     w = jnp.asarray(el.weights[:n]) if weighted else None
-    if method == "global":
-        offsets, targets, ww = build.csr_global(src, dst, w, v, weighted=weighted)
-    elif method == "staged":
-        offsets, targets, ww = build.csr_staged(src, dst, w, v, rho=rho,
-                                                weighted=weighted)
-    elif method == "binned":
-        offsets, targets, ww = build.csr_binned(src, dst, w, v,
-                                                bin_bits=bin_bits,
-                                                weighted=weighted)
-    else:
-        raise ValueError(f"unknown method {method!r}")
+    offsets, targets, ww = build.csr_staged(src, dst, w, v, weighted=weighted)
     return CSR(np.asarray(offsets), np.asarray(targets),
                None if ww is None else np.asarray(ww), v)
 
@@ -67,9 +43,6 @@ def read_csr(
     symmetric: bool = False,
     base: int = 1,
     num_vertices: Optional[int] = None,
-    method: str = "staged",
-    rho: int = 4,
-    bin_bits: Optional[int] = None,
     engine: str = "jax",
     **reader_kwargs,
 ) -> CSR:
@@ -83,8 +56,7 @@ def read_csr(
     from .loader import load_csr
     return load_csr(path, engine="device" if engine == "jax" else engine,
                     weighted=weighted, symmetric=symmetric, base=base,
-                    num_vertices=num_vertices, method=method, rho=rho,
-                    bin_bits=bin_bits, **reader_kwargs)
+                    num_vertices=num_vertices, **reader_kwargs)
 
 
 def csr_to_dense(csr: CSR) -> np.ndarray:

@@ -126,22 +126,13 @@ def build_local_csr(
     *,
     rows_per_shard: int,
     axis: str,
-    rho: int = 4,
-    method: str = "staged",
-    bin_bits: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array, Optional[jax.Array]]:
-    """Shard-local body: rank-based CSR (``staged`` or ``binned``) over
-    this shard's owned vertex range."""
+    """Shard-local body: the staged CSR build (``build.csr_staged``)
+    over this shard's owned vertex range."""
     my = jax.lax.axis_index(axis)
     local = jnp.where(src >= 0, src - my * rows_per_shard, -1)
-    if method == "binned":
-        offsets, targets, ww = build.csr_binned(
-            local, dst, w, rows_per_shard, bin_bits=bin_bits,
-            weighted=w is not None)
-    else:
-        offsets, targets, ww = build.csr_staged(
-            local, dst, w, rows_per_shard, rho=rho, weighted=w is not None)
-    return offsets, targets, ww
+    return build.csr_staged(local, dst, w, rows_per_shard,
+                            weighted=w is not None)
 
 
 def load_csr_sharded(
@@ -152,9 +143,6 @@ def load_csr_sharded(
     w: Optional[jax.Array],
     *,
     num_vertices: int,
-    rho: int = 4,
-    method: str = "staged",
-    bin_bits: Optional[int] = None,
     send_cap: Optional[int] = None,
     edge_limit: Optional[int] = None,
 ) -> CSR:
@@ -188,8 +176,8 @@ def load_csr_sharded(
     lim = e_per if edge_limit is None else max(min(int(edge_limit), e_per), 1)
 
     weighted = w is not None
-    fn = _exchange_build_fn(mesh, axis, d, rows, int(send_cap), rho,
-                            weighted, lim, method, bin_bits)
+    fn = _exchange_build_fn(mesh, axis, d, rows, int(send_cap), weighted,
+                            lim)
     win = w if weighted else jnp.zeros((), jnp.float32)
     off, tgt, tw, ovf = fn(src, dst, win)
     ovf_h = np.asarray(ovf)
@@ -206,10 +194,8 @@ def load_csr_sharded(
 
 @functools.lru_cache(maxsize=64)
 def _exchange_build_fn(mesh: Mesh, axis: str, d: int, rows: int,
-                       send_cap: int, rho: int, weighted: bool,
-                       edge_limit: Optional[int] = None,
-                       method: str = "staged",
-                       bin_bits: Optional[int] = None):
+                       send_cap: int, weighted: bool,
+                       edge_limit: Optional[int] = None):
     """The jitted exchange+build program for one (mesh, geometry) combo.
 
     shard_map over a fresh closure defeats jax's jit cache (new function
@@ -228,8 +214,7 @@ def _exchange_build_fn(mesh: Mesh, axis: str, d: int, rows: int,
             s, dd, ww, num_shards=d, rows_per_shard=rows,
             axis=axis, send_cap=send_cap)
         off, tgt, tw = build_local_csr(rs, rd, rw, rows_per_shard=rows,
-                                       axis=axis, rho=rho, method=method,
-                                       bin_bits=bin_bits)
+                                       axis=axis)
         if tw is None:
             tw = jnp.zeros_like(tgt, jnp.float32)
         return off[None], tgt[None], tw[None], ovf[None]
@@ -461,9 +446,6 @@ def load_csr_sharded_stream(
     num_vertices: Optional[int] = None,
     weighted: bool = False,
     base: int = 1,
-    rho: int = 4,
-    method: str = "staged",
-    bin_bits: Optional[int] = None,
     offset: int = 0,
     send_cap: Optional[int] = None,
     parse: str = "xla",
@@ -508,8 +490,7 @@ def load_csr_sharded_stream(
     with trace.span("load.exchange", shards=d, send_cap=send_cap,
                     edge_limit=edge_limit, edges=sum(counts)):
         return load_csr_sharded(mesh, axis, src, dst, w,
-                                num_vertices=num_vertices, rho=rho,
-                                method=method, bin_bits=bin_bits,
+                                num_vertices=num_vertices,
                                 send_cap=send_cap, edge_limit=edge_limit)
 
 
@@ -521,7 +502,6 @@ def host_shard_and_load(
     num_vertices: int,
     weighted: bool = False,
     base: int = 1,
-    rho: int = 4,
 ) -> CSR:
     """Compatibility wrapper: the historical end-to-end entry point.
 
@@ -534,4 +514,4 @@ def host_shard_and_load(
     """
     return load_csr_sharded_stream(
         mesh, axis, path, num_vertices=num_vertices, weighted=weighted,
-        base=base, rho=rho)
+        base=base)

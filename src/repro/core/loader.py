@@ -44,9 +44,8 @@ The device/pallas engines are *streaming* (GVEL's pipelined read):
      being padded with ``NEWLINE`` blocks to ``batch_blocks`` — small
      inputs don't pay full-batch parse cost for padding;
   4. ``load_csr`` hands the packed device buffers straight to the
-     rank-based CSR builders (``build.csr_global``/``csr_staged``/
-     ``csr_binned``), so file -> CSR never materializes a host-side
-     EdgeList.
+     rank-based staged CSR build (``build.csr_staged``), so file -> CSR
+     never materializes a host-side EdgeList.
 
 Block geometry (``beta`` x ``batch_blocks``) defaults to
 ``DEFAULT_BETA``/``DEFAULT_BATCH_BLOCKS`` and can be *measured* instead:
@@ -122,12 +121,6 @@ class LoadOptions:
     ``engine_kw`` values always win, and non-streaming engines ignore
     it.
 
-    ``method``/``bin_bits`` pick the CSR build strategy for every
-    ``.csr()``-family product off this handle (``method=None`` means the
-    per-call default, ``staged``); a per-call ``method=`` always wins.
-    ``bin_bits`` is the binned build's vertex-range width knob and is
-    ignored by the sort-based methods.
-
     ``faults`` pins a :class:`repro.core.faults.FaultPlan` on the
     handle: every product call runs under that plan (chaos testing a
     single source without touching the process-wide plan).  Never
@@ -141,23 +134,22 @@ class LoadOptions:
     num_vertices: Optional[int] = None
     offset: int = 0
     tune: bool = False
-    method: Optional[str] = None
-    bin_bits: Optional[int] = None
     faults: Optional[Any] = None
     engine_kw: Dict[str, Any] = dataclasses.field(default_factory=dict)
 
     _OWN_FIELDS = ("engine", "weighted", "symmetric", "base",
-                   "num_vertices", "offset", "tune", "method", "bin_bits",
-                   "faults")
+                   "num_vertices", "offset", "tune", "faults")
 
     def __post_init__(self):
         if self.base not in (0, 1):
             raise ValueError(f"base must be 0 or 1, got {self.base!r}")
         if self.offset < 0:
             raise ValueError(f"offset must be >= 0, got {self.offset!r}")
-        if self.method not in (None, "global", "staged", "binned"):
-            raise ValueError(f"unknown method {self.method!r}; expected "
-                             f"'global', 'staged' or 'binned'")
+        fixed = sorted(set(self.engine_kw) & {"method", "bin_bits", "rho"})
+        if fixed:
+            raise TypeError(f"unknown option(s) {fixed}: the CSR build is "
+                            f"not selectable (every CSR is built by "
+                            f"build.csr_staged)")
         dup = sorted(set(self.engine_kw) & set(self._OWN_FIELDS))
         if dup:
             raise ValueError(f"option(s) {dup} passed both named and via "
@@ -574,8 +566,6 @@ def read_edgelist_via(path: str, opts: LoadOptions) -> EdgeList:
 
 
 def read_csr_via(path: str, opts: LoadOptions, *,
-                 method: Optional[str] = None, rho: int = 4,
-                 bin_bits: Optional[int] = None,
                  fallback_edgelist: Optional[Callable[[], EdgeList]] = None,
                  ) -> CSR:
     """File -> CSR through ``opts.engine`` (must be concrete).
@@ -586,12 +576,11 @@ def read_csr_via(path: str, opts: LoadOptions, *,
     ``fallback_edgelist`` lets a :class:`~repro.core.source.GraphSource`
     feed its memoized edgelist into that last route instead of
     re-reading the file.  Symmetric graphs always take the EdgeList
-    route (reverse-edge expansion is a host concatenation today).
-    ``method=None`` falls back to ``opts.method``, then ``staged``.
+    route (reverse-edge expansion is a host concatenation today).  The
+    stream route builds with ``build.csr_staged``; the EdgeList route
+    converts with the engine's backend (:func:`csr_convert_engine`).
     """
     opts = resolve_tuned(opts)
-    method = method or opts.method or "staged"
-    bin_bits = bin_bits if bin_bits is not None else opts.bin_bits
     weighted = bool(opts.weighted)
     eng = get_engine(opts.engine)
     if hasattr(eng, "read_csr_prebuilt") and not opts.symmetric:
@@ -618,18 +607,8 @@ def read_csr_via(path: str, opts: LoadOptions, *,
             if cap2 < src.shape[0]:
                 src, dst = src[:cap2], dst[:cap2]
                 w = w[:cap2] if weighted else None
-            if method == "global":
-                offsets, targets, ww = build.csr_global(
-                    src, dst, w, num_vertices, weighted=weighted)
-            elif method == "staged":
-                offsets, targets, ww = build.csr_staged(
-                    src, dst, w, num_vertices, rho=rho, weighted=weighted)
-            elif method == "binned":
-                offsets, targets, ww = build.csr_binned(
-                    src, dst, w, num_vertices, bin_bits=bin_bits,
-                    weighted=weighted)
-            else:
-                raise ValueError(f"unknown method {method!r}")
+            offsets, targets, ww = build.csr_staged(
+                src, dst, w, num_vertices, weighted=weighted)
         with span("load.sync"):         # so the copy back is only the copy
             jax.block_until_ready((offsets, targets, ww))
         with span("load.copy_back"):
@@ -640,14 +619,11 @@ def read_csr_via(path: str, opts: LoadOptions, *,
     from .csr import convert_to_csr
     el = (fallback_edgelist() if fallback_edgelist is not None
           else read_edgelist_via(path, opts))
-    return convert_to_csr(el, method=method, rho=rho, bin_bits=bin_bits,
-                          engine=csr_convert_engine(opts.engine))
+    return convert_to_csr(el, engine=csr_convert_engine(opts.engine))
 
 
 def read_csr_sharded_via(path: str, opts: LoadOptions, *, mesh,
-                         axis: str = "data", rho: int = 4,
-                         method: Optional[str] = None,
-                         bin_bits: Optional[int] = None) -> CSR:
+                         axis: str = "data") -> CSR:
     """File -> mesh-sharded CSR through ``opts.engine`` (must be a
     streaming engine — the byte-range shard plan only exists for the
     block streaming pipeline).
@@ -675,9 +651,7 @@ def read_csr_sharded_via(path: str, opts: LoadOptions, *, mesh,
             f"streaming engine ('device' or 'pallas')")
     from . import distributed
     return distributed.load_csr_sharded_stream(
-        mesh, axis, path, num_vertices=opts.num_vertices, rho=rho,
-        method=method or opts.method or "staged",
-        bin_bits=bin_bits if bin_bits is not None else opts.bin_bits,
+        mesh, axis, path, num_vertices=opts.num_vertices,
         parse=eng._parse, **opts.stream_kwargs())
 
 
@@ -723,9 +697,6 @@ def load_csr(
     symmetric: bool = False,
     base: int = 1,
     num_vertices: Optional[int] = None,
-    method: str = "staged",
-    rho: int = 4,
-    bin_bits: Optional[int] = None,
     offset: int = 0,
     tune: bool = False,
     **engine_kw,
@@ -733,24 +704,22 @@ def load_csr(
     """File -> CSR through the named engine.
 
     A thin wrapper over the :class:`~repro.core.source.GraphSource`
-    front door — equivalent to ``open_graph(path, ...).csr(...)``.
+    front door — equivalent to ``open_graph(path, ...).csr()``.
     Streaming engines (device, pallas) run fused: one jitted program
     per batch parses the blocks and accumulates the edges in packed
-    (donated) device buffers that feed the rank-based builders
-    (``csr_global``/``csr_staged``/``csr_binned``) directly — no host
-    EdgeList in between.  ``tune=True`` fills un-pinned streaming
-    geometry from the measured per-host profile.  Host engines read an
+    (donated) device buffers that feed the staged build
+    (``build.csr_staged``) directly — no host EdgeList in between.
+    ``tune=True`` fills un-pinned streaming geometry from the measured
+    per-host profile.  Host engines read an
     EdgeList and convert.  Binary ``.gvel`` files are detected by magic
     and routed to the snapshot engine; an embedded prebuilt CSR is
-    served straight from mmap (``method``/``rho``/``bin_bits`` do not
-    apply — the stored CSR wins).
+    served straight from mmap.
     """
     from .source import open_graph
     return open_graph(path, engine=engine, weighted=weighted,
                       symmetric=symmetric, base=base,
                       num_vertices=num_vertices, offset=offset, tune=tune,
-                      validate=False, **engine_kw).csr(method=method, rho=rho,
-                                                       bin_bits=bin_bits)
+                      validate=False, **engine_kw).csr()
 
 
 _register_builtin_engines()

@@ -100,15 +100,12 @@ def read_mtx(path: str, *, engine: str = "numpy", **engine_kw) -> EdgeList:
     return el
 
 
-def read_mtx_csr(path: str, *, method: str = "staged", rho: int = 4,
-                 engine: str = "numpy") -> CSR:
-    return convert_to_csr(read_mtx(path, engine=engine), method=method,
-                          rho=rho, engine=engine)
+def read_mtx_csr(path: str, *, engine: str = "numpy") -> CSR:
+    return convert_to_csr(read_mtx(path, engine=engine), engine=engine)
 
 
 def mtx_to_snapshot(path: str, out_path: str, *, engine: str = "numpy",
-                    csr: bool = True, method: str = "staged", rho: int = 4,
-                    compress: str | None = None,
+                    csr: bool = True, compress: str | None = None,
                     compress_level: int | None = None) -> GraphMeta:
     """Convert an MTX file to a binary ``.gvel`` snapshot (parse once).
 
@@ -127,7 +124,7 @@ def mtx_to_snapshot(path: str, out_path: str, *, engine: str = "numpy",
     src = open_graph(path, engine=engine)
     if src.format != "mtx":
         raise ValueError(f"{path}: missing MatrixMarket banner")
-    src.save(out_path, csr=csr, method=method, rho=rho, compress=compress,
+    src.save(out_path, csr=csr, compress=compress,
              compress_level=compress_level)
     return src._mtx_header().meta
 

@@ -193,8 +193,8 @@ class GraphSource:
         self._info: Optional[SourceInfo] = None
         self._el: Optional[EdgeList] = None
         self._el_engine: Optional[str] = None
-        self._csrs: Dict[Tuple[str, int], CSR] = {}
-        self._sharded_csrs: Dict[Tuple[Any, str, int], CSR] = {}
+        self._csr: Optional[CSR] = None
+        self._sharded_csrs: Dict[Tuple[Any, str], CSR] = {}
         self._mtx_hdr = None
         self._gvel_peek = None                # (version, flags, V, E, entries)
         self._framed_hdr = None               # codecs.FramedInfo
@@ -346,20 +346,11 @@ class GraphSource:
             self._el_engine = opts.engine
         return self._el
 
-    def _build_method(self, method: Optional[str]) -> str:
-        """Per-call ``method`` wins; else the handle's
-        ``LoadOptions.method``; else ``staged``."""
-        return method or self.options.method or "staged"
-
-    def csr(self, *, method: Optional[str] = None, rho: int = 4,
-            bin_bits: Optional[int] = None, rows=None) -> CSR:
-        """The graph as a :class:`CSR`; computed on first call per
-        ``(method, rho, bin_bits)``, memoized on the handle.
-        ``method=None`` resolves to the handle's ``LoadOptions.method``
-        (``open_graph(..., method="binned")``), then ``staged``.  A
-        ``.gvel`` snapshot with an embedded CSR serves it straight from
-        mmap (``method``/``rho``/``bin_bits`` do not apply — the stored
-        CSR wins).
+    def csr(self, *, rows=None) -> CSR:
+        """The graph as a :class:`CSR`; computed on first call,
+        memoized on the handle.  A ``.gvel`` snapshot with an embedded
+        CSR serves it straight from mmap; every other source builds it
+        with the staged build (``build.csr_staged``).
 
         ``rows`` selects a vertex-range slice: a ``range`` with step 1
         (or a ``(lo, hi)`` pair), returning a row-local CSR —
@@ -373,29 +364,20 @@ class GraphSource:
         slicing the full — memoized — CSR, so the result is identical
         either way.  Row slices are not memoized (the full product is;
         slices are cheap and unbounded in number)."""
-        method = self._build_method(method)
-        if bin_bits is None:
-            bin_bits = self.options.bin_bits
         if rows is not None:
-            return self._csr_rows(rows, method=method, rho=rho,
-                                  bin_bits=bin_bits)
-        key = (method, rho, bin_bits)
-        if key not in self._csrs:
+            return self._csr_rows(rows)
+        if self._csr is None:
+            opts = self._opts_for("csr")
             if self.format == FORMAT_MTX:
                 from .csr import convert_to_csr
-                opts = self._opts_for("csr")
-                csr = convert_to_csr(self.edgelist(), method=method, rho=rho,
-                                     bin_bits=bin_bits,
-                                     engine=csr_convert_engine(opts.engine))
+                self._csr = convert_to_csr(
+                    self.edgelist(), engine=csr_convert_engine(opts.engine))
             else:
-                opts = self._opts_for("csr")
                 with fault_plan(opts.faults):
-                    csr = read_csr_via(
-                        self.path, opts, method=method, rho=rho,
-                        bin_bits=bin_bits,
+                    self._csr = read_csr_via(
+                        self.path, opts,
                         fallback_edgelist=lambda: self._edgelist_for(opts))
-            self._csrs[key] = csr
-        return self._csrs[key]
+        return self._csr
 
     def _selective_snap(self):
         """The pinned lazy :class:`Snapshot` when selective reads can
@@ -431,14 +413,12 @@ class GraphSource:
         snap = self._snap
         return None if snap is None else snap.frame_cache_stats()
 
-    def _csr_rows(self, rows, *, method: str, rho: int,
-                  bin_bits: Optional[int] = None) -> CSR:
+    def _csr_rows(self, rows) -> CSR:
         lo, hi = _normalize_rows(rows)
         snap = self._selective_snap()
         if snap is not None:
             return snap.csr_rows(lo, hi, weighted=self._weighted())
-        return slice_csr(self.csr(method=method, rho=rho, bin_bits=bin_bits),
-                         lo, hi)
+        return slice_csr(self.csr(), lo, hi)
 
     def neighbors(self, u: int, *, with_weights: bool = False):
         """Point lookup: vertex ``u``'s neighbor ids as a 1-D int32
@@ -480,12 +460,10 @@ class GraphSource:
                              f"[0, {full.num_rows})")
         return int(full.offsets[u + 1]) - int(full.offsets[u])
 
-    def csr_sharded(self, mesh, *, axis: str = "data", rho: int = 4,
-                    method: Optional[str] = None,
-                    bin_bits: Optional[int] = None) -> CSR:
+    def csr_sharded(self, mesh, *, axis: str = "data") -> CSR:
         """The graph as a :class:`CSR` sharded row-wise across ``mesh``
-        along ``axis``; computed on first call per ``(mesh, axis, rho,
-        method, bin_bits)``, memoized on the handle.
+        along ``axis``; computed on first call per ``(mesh, axis)``,
+        memoized on the handle.
 
         Each mesh shard streams only its byte-range span of the file
         through the fused parse pipeline (:func:`repro.core.blocks.
@@ -510,15 +488,11 @@ class GraphSource:
                 f"byte-range sharded streaming applies to text "
                 f"edgelists; use .csr() and shard the result, or keep "
                 f"the original text file for sharded loads")
-        method = self._build_method(method)
-        if bin_bits is None:
-            bin_bits = self.options.bin_bits
-        key = (mesh, axis, int(rho), method, bin_bits)
+        key = (mesh, axis)
         if key not in self._sharded_csrs:
             with fault_plan(self.options.faults):
                 self._sharded_csrs[key] = read_csr_sharded_via(
-                    self.path, self._opts_for("csr"), mesh=mesh, axis=axis,
-                    rho=rho, method=method, bin_bits=bin_bits)
+                    self.path, self._opts_for("csr"), mesh=mesh, axis=axis)
         return self._sharded_csrs[key]
 
     def _edgelist_for(self, opts: LoadOptions) -> EdgeList:
@@ -577,8 +551,8 @@ class GraphSource:
     # -- write path ----------------------------------------------------------
 
     def save(self, out_path: str, *, compress: Optional[str] = None,
-             compress_level: Optional[int] = None, csr: bool = True,
-             method: Optional[str] = None, rho: int = 4) -> "GraphSource":
+             compress_level: Optional[int] = None,
+             csr: bool = True) -> "GraphSource":
         """Write this graph as a ``.gvel`` snapshot and return a handle
         on the output — the symmetric write path ("write once, load
         many").  ``compress`` accepts a codec spec (``"zlib"``,
@@ -586,7 +560,6 @@ class GraphSource:
         Products are reused: a memoized edgelist/CSR is not recomputed.
         """
         from .snapshot import SnapshotError, save_snapshot
-        method = self._build_method(method)
         if compress is not None:
             from .codecs import parse_codec_spec
             codec, level = parse_codec_spec(compress)
@@ -603,17 +576,15 @@ class GraphSource:
             el = self.edgelist()
             csr_obj = None
             if csr:
-                key = (method, rho)
-                if self.format == FORMAT_TEXT and key not in self._csrs:
+                if self.format == FORMAT_TEXT and self._csr is None:
                     # both products are needed: build the CSR from the
                     # edgelist just parsed instead of re-parsing the file
                     # on the streaming fast path (one parse per save)
                     from .csr import convert_to_csr
                     opts = self._opts_for("csr")
-                    self._csrs[key] = convert_to_csr(
-                        el, method=method, rho=rho,
-                        engine=csr_convert_engine(opts.engine))
-                csr_obj = self.csr(method=method, rho=rho)
+                    self._csr = convert_to_csr(
+                        el, engine=csr_convert_engine(opts.engine))
+                csr_obj = self.csr()
         save_snapshot(out_path, edgelist=el, csr=csr_obj, compress=compress,
                       compress_level=compress_level)
         return GraphSource(out_path, LoadOptions(), validate=True)
@@ -630,8 +601,6 @@ def open_graph(
     symmetric: bool = False,
     num_vertices: Optional[int] = None,
     tune: bool = False,
-    method: Optional[str] = None,
-    bin_bits: Optional[int] = None,
     faults: Optional[Any] = None,
     **engine_kw,
 ) -> GraphSource:
@@ -652,19 +621,14 @@ def open_graph(
     ``batch_blocks``, ``num_workers``, ...).  ``tune=True`` fills
     un-pinned streaming block geometry from the measured per-host
     profile (:mod:`repro.core.tune`; first use on a host runs the
-    sweep and caches it — see docs/performance.md).  ``method``
-    (``"global"``/``"staged"``/``"binned"``) pins the CSR build
-    strategy for every ``.csr()``-family product off the handle, and
-    ``bin_bits`` sets the binned build's vertex-range width; a per-call
-    ``csr(method=...)`` still wins.  ``faults`` pins a
+    sweep and caches it — see docs/performance.md).  ``faults`` pins a
     :class:`repro.core.faults.FaultPlan` on the handle — every product
     load runs under that plan (see docs/robustness.md).
     """
     opts = LoadOptions(engine=engine, weighted=weighted, symmetric=symmetric,
                        base=1 if base is None else base,
                        num_vertices=num_vertices, offset=offset, tune=tune,
-                       method=method, bin_bits=bin_bits, faults=faults,
-                       engine_kw=dict(engine_kw))
+                       faults=faults, engine_kw=dict(engine_kw))
     with span("load.open"):
         return GraphSource(path, opts, validate=validate)
 

@@ -61,8 +61,6 @@ class CorpusConfig:
     vocab_size: int = 256             # token ids = vertex ids mod vocab
     seed: int = 99                    # corpus-level PRNG root
     lookahead: int = 2                # prefetch queue depth
-    method: Optional[str] = None      # CSR build method (source default)
-    rho: int = 4
 
 
 class WalkCorpus:
@@ -82,7 +80,7 @@ class WalkCorpus:
         """The source's CSR as device int32 arrays, pinned on the
         corpus (one transfer per corpus, not per batch)."""
         if self._offsets is None:
-            csr = self.source.csr(method=self.cfg.method, rho=self.cfg.rho)
+            csr = self.source.csr()
             self._offsets = jnp.asarray(np.asarray(csr.offsets), I32)
             self._targets = jnp.asarray(np.asarray(csr.targets), I32)
             self._num_vertices = int(csr.num_vertices)

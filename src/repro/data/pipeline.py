@@ -33,12 +33,9 @@ def graph_walk_source(path: str, cfg, batch: int, seq: int, *,
     from ..core.source import open_graph
     from .corpus import CorpusConfig, WalkCorpus
 
-    method = load_kw.pop("method", "staged")
-    rho = load_kw.pop("rho", 4)
     src = open_graph(path, engine=engine, **load_kw)
     corpus = WalkCorpus(src, CorpusConfig(
-        batch=batch, seq=seq, vocab_size=cfg.vocab_size, seed=seed,
-        method=method, rho=rho))
+        batch=batch, seq=seq, vocab_size=cfg.vocab_size, seed=seed))
     return corpus.batch_at
 
 

@@ -46,7 +46,7 @@ def test_jax_reader_block_size_invariance(graph_file, beta):
 
 def test_read_csr_matches_pigo_csr(graph_file):
     path, v, e = graph_file
-    csr = read_csr(path, num_vertices=v, method="staged", rho=4)
+    csr = read_csr(path, num_vertices=v)
     el = baselines.read_edgelist_pigo(path, num_vertices=v)
     ref = baselines.csr_pigo(el)
     assert np.array_equal(np.asarray(csr.offsets, np.int64),

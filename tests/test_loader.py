@@ -146,7 +146,7 @@ def test_streaming_device_csr_large_graph(tmp_path):
     path = str(tmp_path / "big.el")
     v, e = make_graph_file(path, "rmat", scale=16, edge_factor=16, seed=1)
     assert e >= 1_000_000
-    csr = load_csr(path, engine="device", num_vertices=v, method="staged")
+    csr = load_csr(path, engine="device", num_vertices=v)
     el = read_edgelist_numpy(path, num_vertices=v)
     n = int(el.num_edges)
     oracle = csr_np(np.asarray(el.src[:n]), np.asarray(el.dst[:n]), None, v)

@@ -214,7 +214,7 @@ def test_csr_sharded_front_door_rejects_mtx_and_gvel(tmp_path):
 
 def test_csr_sharded_single_device_memoized(tmp_path):
     """d=1 degenerate mesh: the sharded path reduces to the streaming
-    load; memoized per (mesh, axis, rho)."""
+    load; memoized per (mesh, axis)."""
     from repro.core import build, open_graph
     from repro.core.compat import make_mesh
 
@@ -229,7 +229,8 @@ def test_csr_sharded_single_device_memoized(tmp_path):
     g = open_graph(str(path), engine="device", beta=2048)
     csr = g.csr_sharded(mesh)
     assert g.csr_sharded(mesh) is csr
-    assert g.csr_sharded(mesh, rho=8) is not csr
+    assert g.csr_sharded(mesh, axis="data") is csr
+    assert list(g._sharded_csrs) == [(mesh, "data")]
 
     oracle = build.csr_np(src, dst, None, v)
     off = np.asarray(csr.offsets)

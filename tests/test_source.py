@@ -271,9 +271,21 @@ def test_products_memoized(tmp_path):
     src = open_graph(path, num_vertices=v)
     assert src.edgelist() is src.edgelist()
     assert src.csr() is src.csr()
-    assert src.csr(method="staged", rho=4) is src.csr()
-    assert src.csr(method="global") is not src.csr()
+    assert src._csr is src.csr()             # one whole-graph CSR memo
     assert src.info() is src.info()
+
+
+@pytest.mark.parametrize("call", [
+    lambda p: open_graph(p, method="staged").csr(),
+    lambda p: open_graph(p).csr(method="staged"),
+    lambda p: open_graph(p).csr(rho=8),
+], ids=["open_graph_method", "csr_method", "csr_rho"])
+def test_build_options_rejected(tmp_path, call):
+    """The CSR build is not selectable: a build option raises instead of
+    being forwarded to an engine or building silently."""
+    path, v, e, _ = _graph(tmp_path, weighted=False, base=1, seed=2)
+    with pytest.raises(TypeError):
+        call(path)
 
 
 def test_csr_fallback_reuses_memoized_edgelist(tmp_path, monkeypatch):

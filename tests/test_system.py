@@ -26,7 +26,7 @@ def test_end_to_end_graph_to_training(graph):
     """The paper's technique as the data substrate: text file -> staged CSR
     -> random-walk corpus -> LM training; loss must drop."""
     path, v, e = graph
-    csr = read_csr(path, num_vertices=v, method="staged", rho=4)
+    csr = read_csr(path, num_vertices=v)
     assert int(csr.offsets[-1]) == e
 
     cfg = reduced_config("phi4-mini-3.8b")
@@ -63,7 +63,7 @@ def test_end_to_end_with_prefetcher(graph):
 
 def test_jax_engine_matches_numpy_engine_on_csr(graph):
     path, v, e = graph
-    a = read_csr(path, num_vertices=v, engine="jax", method="staged")
+    a = read_csr(path, num_vertices=v, engine="jax")
     b = read_csr(path, num_vertices=v, engine="numpy")
     assert np.array_equal(np.asarray(a.offsets, np.int64),
                           np.asarray(b.offsets))

@@ -126,7 +126,7 @@ def test_mesh_exchange_build_compiles_for_v5e_2x2(topo, no_compile_cache):
     mesh = Mesh(np.array(topo.devices[:d]), ("data",))
     e_per = 992 * (BUF_LEN // 4 + 2)
     fn = distributed._exchange_build_fn(mesh, "data", d, V // d, 3 << 21,
-                                        4, False, 3 << 23)
+                                        False, 3 << 23)
     edges = jax.ShapeDtypeStruct((d * e_per,), jnp.int32,
                                  sharding=NamedSharding(mesh, P("data")))
     no_w = jax.ShapeDtypeStruct((), jnp.float32,

@@ -21,7 +21,7 @@ def csr(tmp_path_factory):
     path = str(tmp_path_factory.mktemp("w") / "g.el")
     v, e = make_graph_file(path, "rmat", scale=8, edge_factor=8, seed=11)
     el = read_edgelist_numpy(path, num_vertices=v)
-    return convert_to_csr(el, method="staged")
+    return convert_to_csr(el)
 
 
 def _arrays(csr):
