@@ -14,6 +14,11 @@ import os
 import numpy as np
 
 NEWLINE = 10
+# The shortest line the parser accepts as an edge: two tokens, the
+# whitespace byte that parts them and the newline ("1 2\n").  A line
+# with fewer than two tokens is no edge, so two edge-ending newlines of
+# one block buffer lie at least this many bytes apart.
+MIN_LINE_BYTES = 4
 
 
 def mmap_bytes(path: str, offset: int = 0) -> np.ndarray:
@@ -45,8 +50,8 @@ class BlockPlan:
 
     @property
     def edge_cap(self) -> int:
-        # min parsable line is 4 bytes ("1 2\n"); +2 slack
-        return self.buf_len // 4 + 2
+        # at most one edge per MIN_LINE_BYTES bytes; +2 slack
+        return self.buf_len // MIN_LINE_BYTES + 2
 
 
 def plan_blocks(file_len: int, beta: int = 256 * 1024, overlap: int = 64) -> BlockPlan:

@@ -39,11 +39,12 @@ Two entry points wrap it:
   device accumulators** (donated, so the update is in-place where the
   backend supports buffer donation — see :func:`donation_supported`).
   The per-block ``(nb, edge_cap)`` intermediates of the two-step
-  parse-then-accumulate pipeline never materialize; the batch-wide
-  compaction (:func:`_compact_accumulate`) costs exactly one scatter
-  per batch, which is where the streaming engine's speedup over the
-  batch round-trip lives.  The Pallas engine shares the same
-  compaction through ``kernels.parse_edges.parse_edges_accumulate``.
+  parse-then-accumulate pipeline never materialize.  The batch-wide
+  compaction (:func:`_compact_accumulate`) folds each block 4:1 first,
+  since an edge line is at least ``blocks.MIN_LINE_BYTES`` long, and
+  scatters the values over a quarter of the batch's bytes, with no
+  gather.  The Pallas engine shares the same compaction through
+  ``kernels.parse_edges.parse_edges_accumulate``.
 
 Limits (documented): vertex ids must have <= 9 decimal digits (int32 math;
 covers every graph in the paper, max |V| = 214M), weights are plain
@@ -65,6 +66,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .blocks import MIN_LINE_BYTES
 from .trace import span
 
 I32 = jnp.int32
@@ -276,6 +278,19 @@ def _parse_block_bytes(buf, owned_start, owned_end, *, weighted: bool,
     return valid, src - base, dst - base, w
 
 
+def _fold(x, init):
+    """``(nb, blen)`` -> ``(nb, ceil(blen / MIN_LINE_BYTES))``: the max of
+    each aligned group of ``MIN_LINE_BYTES`` bytes of a block, a block's
+    ragged end padded with ``init``.  A ``reduce_window``, since for a
+    TPU v5e a reshape to a minor axis of 4 compiles with that axis padded
+    to 128 lanes (135 MB of temporaries a batch), and strided slices
+    compile to gathers."""
+    k = MIN_LINE_BYTES
+    return jax.lax.reduce_window(
+        x, jnp.asarray(init, x.dtype), jax.lax.max, (1, k), (1, k),
+        ((0, 0), (0, -x.shape[1] % k)))
+
+
 def _compact_accumulate(acc_src, acc_dst, acc_w, total, valid, src, dst, w,
                         *, edge_bound: int):
     """Pack a batch of per-byte parses into the accumulators at ``total``.
@@ -286,31 +301,40 @@ def _compact_accumulate(acc_src, acc_dst, acc_w, total, valid, src, dst, w,
     same compaction after its kernel).  Blocks pack consecutively and
     edges within a block stay in line order — the same edge order the
     two-step parse_blocks + accumulate pipeline produced.
+
+    Valid bytes of a block lie at least ``MIN_LINE_BYTES`` apart, so each
+    aligned group of that many bytes holds at most one, and a group's max
+    over its valid bytes is that byte's value, exactly.  The compaction
+    runs over the folded groups, a quarter of the bytes, and scatters the
+    values themselves: on a TPU v5e a scatter costs 5-6 ns an update,
+    dropped or not, and a gather 7-15 ns an index, so two scatters of a
+    quarter of the batch cost less than one over its bytes plus gathers
+    at every slot (0.68 s against 2.35 s a Graph500 scale-20 text load).
     """
-    valid_f = valid.reshape(-1)
-    flat_n = valid_f.shape[0]
-    # batch-wide exclusive compaction
-    dest = jnp.cumsum(valid_f.astype(I32)) - 1
+    g = _fold(valid, False).reshape(-1)
+    dest = jnp.cumsum(g.astype(I32)) - 1
     count = jnp.maximum(dest[-1] + 1, 0)
-    # one scatter packs byte positions; values then come from gathers
-    # (scatter is the slow primitive on CPU XLA — use exactly one)
-    pos = jnp.full((edge_bound,), flat_n, I32).at[
-        jnp.where(valid_f, dest, edge_bound)].set(
-            jnp.arange(flat_n, dtype=I32), mode="drop")
-    pv = pos < flat_n
-    posc = jnp.minimum(pos, flat_n - 1)
-    src_w = jnp.where(pv, src.reshape(-1)[posc], -1)
-    dst_w = jnp.where(pv, dst.reshape(-1)[posc], -1)
+    at = jnp.where(g, dest, edge_bound)
+    int_min = jnp.iinfo(np.int32).min
+
+    def pack(x, pad):
+        """Each valid byte's ``x`` at its edge's slot, ``pad`` elsewhere;
+        float values fold as their int32 bits, so they pack bit for bit."""
+        bits = jax.lax.bitcast_convert_type(x, I32)
+        folded = _fold(jnp.where(valid, bits, int_min), int_min).reshape(-1)
+        values = jax.lax.bitcast_convert_type(folded, x.dtype)
+        return jnp.full((edge_bound,), pad, x.dtype).at[at].set(
+            values, mode="drop")
+
     # a fixed-size window written at the running offset: with donation
     # this lowers to an in-place memcpy of edge_bound elements; invalid
     # window slots carry the accumulator's padding values, and the next
     # batch's window starts where this batch's edges end, so padding
     # never buries an edge
-    acc_src = jax.lax.dynamic_update_slice(acc_src, src_w, (total,))
-    acc_dst = jax.lax.dynamic_update_slice(acc_dst, dst_w, (total,))
+    acc_src = jax.lax.dynamic_update_slice(acc_src, pack(src, -1), (total,))
+    acc_dst = jax.lax.dynamic_update_slice(acc_dst, pack(dst, -1), (total,))
     if acc_w is not None and w is not None:
-        w_w = jnp.where(pv, w.reshape(-1)[posc], 0.0)
-        acc_w = jax.lax.dynamic_update_slice(acc_w, w_w, (total,))
+        acc_w = jax.lax.dynamic_update_slice(acc_w, pack(w, 0.0), (total,))
     return acc_src, acc_dst, acc_w, total + count
 
 

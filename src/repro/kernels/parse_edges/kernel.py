@@ -20,10 +20,11 @@ those bytes — the same contract as ``core.parse._parse_block_bytes``.
 This body looks values up at running max/min positions (gathers); the
 twin computes them as gather-free fills.  The two agree at every valid
 byte, so they match after compaction.  Compaction is
-deliberately *outside* the kernel: the fused loader path packs a whole
-batch with one scatter (``core.parse._compact_accumulate``) straight
-into the donated accumulators, and the standalone ``parse_edges`` entry
-compacts per block.  The body has no scatter, but its scans and
+deliberately *outside* the kernel: the fused loader path folds a whole
+batch 4:1 and scatters its values, with no gather
+(``core.parse._compact_accumulate``), straight into the donated
+accumulators, and the standalone ``parse_edges`` entry compacts per
+block.  The body has no scatter, but its scans and
 gathers are what Mosaic refuses (above).
 
 `weighted` is a *Python-level* specialization parameter — the paper found

@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core import parse as parse_core
+from ...core.blocks import MIN_LINE_BYTES
 from .kernel import parse_bytes_kernel, parse_edges_kernel
 from .ref import parse_edges_ref
 
@@ -41,7 +42,7 @@ def parse_edges(bufs, owned_start: int, owned_end: int, *, weighted: bool = Fals
     """
     nb, buf_len = bufs.shape
     if edge_cap is None:
-        edge_cap = buf_len // 4 + 2
+        edge_cap = buf_len // MIN_LINE_BYTES + 2
     owned = jnp.asarray([owned_start, owned_end], jnp.int32)
     if use_kernel:
         return parse_edges_kernel(bufs, owned, weighted=weighted, base=base,
@@ -96,7 +97,7 @@ def parse_edges_accumulate(acc_src, acc_dst, acc_w, total, bufs,
     """
     nb, buf_len = bufs.shape
     if edge_bound is None:
-        edge_bound = nb * (buf_len // 4 + 2)
+        edge_bound = nb * (buf_len // MIN_LINE_BYTES + 2)
     if donate is None:
         donate = parse_core.donation_supported()
     owned = jnp.asarray([owned_start, owned_end], jnp.int32)

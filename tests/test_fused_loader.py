@@ -170,6 +170,69 @@ def test_tail_remainder_not_padded(tmp_path):
 
 
 # ---------------------------------------------------------------------------
+# the batch compaction's 4:1 fold, at its limit: edge lines 4 bytes apart
+# ---------------------------------------------------------------------------
+
+def _dense(lead):
+    """The densest legal text, ``a b\n`` back to back after ``lead``
+    blank lines, so its newlines fall at each alignment in turn."""
+    return b"\n" * lead + b"".join(
+        b"%d %d\n" % (i % 10, i * 7 % 10) for i in range(1500))
+
+
+_FOLD_TEXTS = {
+    **{f"dense{k}": (_dense(k), False) for k in range(4)},
+    "crlf": (b"".join(b"%d %d\r\n" % (i % 10, i % 7)
+                      for i in range(1200)), False),
+    "tabs-blank-bad": (b"1\t2\n\n3 4\n5 x 6\n7 8\n\n\n9\t0\n1 2\nx\n3 4\n"
+                       * 150, False),
+    "weighted": (b"1 2 -0.0\n3 4 -0\n5 6 1.5\n7 8\n9 1 -2.25\n2 3 0\n"
+                 * 150, True),
+}
+
+
+@pytest.mark.parametrize("beta,overlap", [(1024, 64), (1023, 62),
+                                          (1022, 64), (1021, 62)])
+@pytest.mark.parametrize("case", list(_FOLD_TEXTS))
+def test_fold_compaction_bitwise_equals_parse_blocks(case, beta, overlap):
+    """``parse_accumulate`` folds each block 4:1 before it compacts; its
+    accumulators, padding included, equal ``parse_blocks`` packed on the
+    host bit for bit.  Buffers of 1088, 1085, 1086 and 1083 bytes: the
+    fold's groups align with a block's end, or a ragged group of 1, 2 or
+    3 bytes pads it."""
+    text, weighted = _FOLD_TEXTS[case]
+    data = np.frombuffer(text, np.uint8)
+    plan = plan_blocks(len(data), beta=beta, overlap=overlap)
+    os_, oe = owned_range(plan)
+    nb, ec = plan.num_blocks, plan.edge_cap
+    bufs = jnp.asarray(stage_blocks(data, plan, np.arange(nb)))
+    ostart, oend = jnp.full((nb,), os_, I32), jnp.full((nb,), oe, I32)
+    src_b, dst_b, w_b, counts = map(np.asarray, (
+        x if x is not None else jnp.zeros(()) for x in parse_blocks(
+            bufs, ostart, oend, weighted=weighted, base=0, edge_cap=ec)))
+    cap = nb * ec + 7
+    want_s, want_d = np.full(cap, -1, np.int32), np.full(cap, -1, np.int32)
+    want_w = np.zeros(cap, np.float32)
+    n = 0
+    for b, c in enumerate(counts):
+        want_s[n:n + c], want_d[n:n + c] = src_b[b, :c], dst_b[b, :c]
+        if weighted:
+            want_w[n:n + c] = w_b[b, :c]
+        n += c
+    got = parse_accumulate(*parse_mod.make_accumulators(cap, weighted=weighted),
+                           bufs, ostart, oend, weighted=weighted, base=0,
+                           edge_bound=nb * ec, donate=False)
+    assert int(got[3]) == n > 0
+    assert np.array_equal(np.asarray(got[0]), want_s)
+    assert np.array_equal(np.asarray(got[1]), want_d)
+    if weighted:
+        assert np.array_equal(np.asarray(got[2]).view(np.int32),
+                              want_w.view(np.int32))
+        neg_zero = (want_w[:n] == 0) & np.signbit(want_w[:n])
+        assert neg_zero.sum() == 2 * 150                   # -0.0 and -0
+
+
+# ---------------------------------------------------------------------------
 # donation: in-place accumulation and its documented fallback
 # ---------------------------------------------------------------------------
 
